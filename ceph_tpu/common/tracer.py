@@ -2,7 +2,8 @@
 profiling (reference: src/tracing/*.tp LTTng tracepoints,
 src/common/tracer.{h,cc} Jaeger spans; SURVEY.md §5.1).
 
-Three layers, all gated on ONE attribute check when disabled:
+Three layers; the first two are gated on ONE attribute check when
+disabled, the third on the profiler session:
 
 - **Tracepoints**: ``tracepoint(subsys, event, **fields)`` appends a
   timestamped record to a bounded in-memory ring (the LTTng-userspace
@@ -34,9 +35,10 @@ Three layers, all gated on ONE attribute check when disabled:
 
 - **Device profiling**: ``device_trace(logdir)`` wraps
   ``jax.profiler``'s trace context so TPU hot paths emit XPlanes, and
-  ``kernel_annotation(name, trace_ids)`` wraps individual kernel
-  launches in named ``jax.profiler`` annotations keyed by trace_id so
-  the device trace correlates with host spans.
+  ``stage(name, trace_ids)`` brackets the host stages of the device
+  paths (``DEVICE_STAGES``) in named ``jax.profiler`` annotations, on
+  the profiler's clock and keyed by trace_id, so that the device
+  trace's idle gaps name the host stage they fall in.
 
 Stage taxonomy (shared verbatim by ``TrackedOp.mark_event`` offsets,
 the ``stage_*`` latency histograms, and span names — one clock,
@@ -58,7 +60,7 @@ import threading
 import time
 
 from .lockdep import make_lock
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 
 _MAX_EVENTS = 10_000
 _MAX_SPANS = 20_000
@@ -95,6 +97,27 @@ BG_STAGES = (
 READ_STAGES = (
     "read_gather",        # chunk fan-out wall time (batched or per-op)
     "read_decode",        # degraded reconstruct (ranged window or full)
+)
+
+#: host stages of the device paths, as ``stage(name)`` emits them
+#: (``cephtrace:<name>`` profiler spans; docs/tracing.md tabulates where
+#: each sits).  Spans nest: ``gf.*`` inside ``ec.*``, ``crush.*`` and
+#: ``osdmap.post_pass`` inside ``osdmap.map_pool``
+DEVICE_STAGES = (
+    "ec.encode",          # RSCodec.encode_chunks, the whole call
+    "ec.decode",          # RSCodec.decode_chunks, the whole call
+    "ec.stack",           # decode's survivor np.stack
+    "ec.decode_matrix",   # decode-matrix build on a cache miss
+    "gf.h2d",             # commit of a GF apply's input to the device
+    "gf.dispatch",        # kron lookup, matrix commits, pad, launch
+    "gf.wait",            # block on a GF apply's result
+    "gf.d2h",             # fetch of the ready result to the host
+    "osdmap.map_pool",    # OSDMap.map_pool, the whole call
+    "osdmap.post_pass",   # upmap overrides, up filter, primaries
+    "crush.launch",       # xs/weight commit and each rule-fn dispatch
+    "crush.wait",         # block on the raw [N, numrep] result
+    "crush.d2h",          # fetch of that result to the host
+    "ec_encode_inline",   # the write batcher's inline per-op encode
 )
 
 #: every (subsys, event) tracepoint name the package may emit, as
@@ -520,20 +543,18 @@ def device_trace(logdir: str | None = None):
         yield
 
 
-def kernel_annotation(name: str, trace_ids=()):
-    """Named jax.profiler annotation around a kernel launch, keyed by
-    trace_id, so the device trace's XPlanes (TensorBoard/Perfetto)
-    correlate with host spans.  Null when tracing is off — kernel
-    dispatch stays annotation-free on the hot path."""
-    if not TRACER.enabled:
-        return nullcontext()
-    try:
-        import jax
-    except ImportError:  # pragma: no cover - jax is baked into the image
-        return nullcontext()
-    ids = list(trace_ids)
+def stage(name: str, trace_ids=()):
+    """Host span ``cephtrace:<name>`` (a ``DEVICE_STAGES`` entry) on the
+    JAX profiler's clock, the clock of the device ops, keyed by the
+    first trace_id (``#trace=<id>[+n]``) where the op is traced.  Not
+    gated on ``TRACER.enabled``: the profiler records it only while a
+    session collects, and it costs about half a microsecond otherwise.
+    Host code only — never inside a jitted or Pallas function."""
+    from jax.profiler import TraceAnnotation
+
     label = f"cephtrace:{name}"
-    if ids:
+    if trace_ids:
+        ids = list(trace_ids)
         label += f"#trace={ids[0]}" + (f"+{len(ids) - 1}" if len(ids) > 1
                                        else "")
-    return jax.profiler.TraceAnnotation(label)
+    return TraceAnnotation(label)

@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common.kernel_telemetry import TELEMETRY
+from ..common.tracer import stage
 from .batched import (
     I64Engine,
     LimbEngine,
@@ -517,15 +518,16 @@ def _launch_rule_fn(cm, cached, xs, numrep, weightvec) -> jnp.ndarray:
     # weights below 0x10000 (values above mean "always in")
     ctx = enable_x64() if engine_mode != "limb" else contextlib.nullcontext()
     with ctx:
-        xs_np = np.asarray(xs, dtype=np.int32)
-        if engine_mode == "limb":
-            weightvec = jnp.asarray(
-                np.minimum(
-                    np.asarray(weightvec, dtype=np.int64), 0x10000
-                ).astype(np.int32)
-            )
-        else:
-            weightvec = jnp.asarray(weightvec, dtype=jnp.int64)
+        with stage("crush.launch"):
+            xs_np = np.asarray(xs, dtype=np.int32)
+            if engine_mode == "limb":
+                weightvec = jnp.asarray(
+                    np.minimum(
+                        np.asarray(weightvec, dtype=np.int64), 0x10000
+                    ).astype(np.int32)
+                )
+            else:
+                weightvec = jnp.asarray(weightvec, dtype=jnp.int64)
         N = xs_np.shape[0]
         # chunk by LANES (N x max step width), not raw N: a multi-choose
         # step fans each x out to its working-vector width
@@ -541,8 +543,9 @@ def _launch_rule_fn(cm, cached, xs, numrep, weightvec) -> jnp.ndarray:
 
         if N <= chunk_n:
             Np = padded_width(N)
-            out = vf(jnp.asarray(np.resize(xs_np, Np)), weightvec)
-            return out[:N] if Np != N else out
+            with stage("crush.launch"):
+                out = vf(jnp.asarray(np.resize(xs_np, Np)), weightvec)
+                return out[:N] if Np != N else out
         # Large batches run as fixed-size device calls: one launch over
         # >~512k lanes (int64 while-loops) hard-faults the v5e worker, and
         # a single huge launch would also hold the whole [lanes, S]
@@ -556,7 +559,10 @@ def _launch_rule_fn(cm, cached, xs, numrep, weightvec) -> jnp.ndarray:
             # the small-batch path compiles anyway — not to a full chunk
             width = padded_width(len(part))
             padded = np.resize(part, width)
-            pieces.append(
-                np.asarray(vf(jnp.asarray(padded), weightvec))[: len(part)]
-            )
+            with stage("crush.launch"):
+                raw = vf(jnp.asarray(padded), weightvec)
+            with stage("crush.wait"):
+                raw.block_until_ready()  # noqa: CL8 — each chunk's fetch is the deliberate sync
+            with stage("crush.d2h"):
+                pieces.append(np.asarray(raw)[: len(part)])
         return jnp.asarray(np.concatenate(pieces))

@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from ...common.tracer import stage
 from ...gf.matrix import (
     cauchy_good_coding_matrix,
     cauchy_original_coding_matrix,
@@ -59,6 +60,15 @@ def build_coding_matrix(technique: str, k: int, m: int) -> np.ndarray:
         f"unknown technique {technique!r}; known: "
         f"{TECHNIQUES + BITMATRIX_TECHNIQUES}"
     )
+
+
+def _to_host(result) -> np.ndarray:
+    """Host copy of a GF apply's device result: the wait for the kernel,
+    then the fetch, each its own stage (np.asarray alone does both)."""
+    with stage("gf.wait"):
+        result.block_until_ready()
+    with stage("gf.d2h"):
+        return np.asarray(result)
 
 
 class RSCodec(ErasureCode):
@@ -95,23 +105,31 @@ class RSCodec(ErasureCode):
         return True
 
     def encode_chunks(self, data_chunks: np.ndarray) -> np.ndarray:
-        data_chunks = np.ascontiguousarray(data_chunks, dtype=np.uint8)
-        if self.backend == "jax":
-            return np.asarray(self._jax_codec.encode(data_chunks))
-        if self.backend == "oracle":
-            from ... import native_oracle
+        with stage("ec.encode"):
+            data_chunks = np.ascontiguousarray(data_chunks, dtype=np.uint8)
+            if self.backend == "jax":
+                return _to_host(self._jax_codec.encode(data_chunks))
+            if self.backend == "oracle":
+                from ... import native_oracle
 
-            return native_oracle.encode(self.coding, data_chunks, fast=True)
-        from ...gf.reference_codec import encode_chunks as np_encode
+                return native_oracle.encode(self.coding, data_chunks,
+                                            fast=True)
+            from ...gf.reference_codec import encode_chunks as np_encode
 
-        return np_encode(self.coding, data_chunks)
+            return np_encode(self.coding, data_chunks)
 
     def decode_chunks(self, want_to_read, chunks: dict[int, np.ndarray]):
+        with stage("ec.decode"):
+            return self._decode_chunks(want_to_read, chunks)
+
+    def _decode_chunks(self, want_to_read, chunks: dict[int, np.ndarray]):
         avail = sorted(chunks)
         if len(avail) < self.k:
             raise InsufficientChunks(f"need {self.k}, have {len(avail)}")
         use = avail[: self.k]
-        shards = np.stack([np.asarray(chunks[r], dtype=np.uint8) for r in use])
+        with stage("ec.stack"):
+            shards = np.stack([np.asarray(chunks[r], dtype=np.uint8)
+                               for r in use])
         if self.backend == "jax":
             # cephdma: the gathered helper stack commits to the device
             # through the stripe pool (recovery's _rebuild_shard_chunk
@@ -119,9 +137,10 @@ class RSCodec(ErasureCode):
             # of one geometry recycle buffers instead of allocating
             from ...ops.device_pool import POOL
 
-            dev = POOL.put(shards) if POOL.enabled() else shards
+            with stage("gf.h2d"):
+                dev = POOL.put(shards) if POOL.enabled() else shards
             try:
-                data = np.asarray(self._jax_codec.decode(use, dev))
+                data = _to_host(self._jax_codec.decode(use, dev))
             finally:
                 # a decode failure (bad shard set, kernel abort) must
                 # not strand the pooled stripe buffer
@@ -148,9 +167,13 @@ class RSCodec(ErasureCode):
                 self.coding[[w - self.k for w in missing_par]]
             )
             if self.backend == "jax":
+                import jax.numpy as jnp
+
                 from ...ops.bitplane import apply_matrix_jax
 
-                par = np.asarray(apply_matrix_jax(rowmat, data))
+                with stage("gf.h2d"):
+                    dev_data = jnp.asarray(data)
+                par = _to_host(apply_matrix_jax(rowmat, dev_data))
             else:
                 from ...gf.reference_codec import apply_matrix
 

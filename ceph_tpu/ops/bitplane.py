@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common.kernel_telemetry import SENTINEL, TELEMETRY
+from ..common.tracer import stage
 from ..gf.matrix import decode_matrix_for, matrix_to_bitmatrix, systematic_generator
 
 _BIT_IDX = np.arange(8, dtype=np.uint8)
@@ -198,29 +199,31 @@ def _apply_matrix_dispatch(mat: np.ndarray, chunks,
     failure here is a fault to see, not one to hide behind XLA.
     `donate=True` routes the XLA path through the donation-enabled jit
     (caller owns `chunks` exclusively — the pooled pack contract); the
-    Pallas route ignores it (its VMEM kernel manages its own buffers)."""
-    if _want_pallas():
-        from .pallas_gf import apply_matrix_pallas
+    Pallas route ignores it (its VMEM kernel manages its own buffers).
+    The whole body is the `gf.dispatch` stage."""
+    with stage("gf.dispatch"):
+        if _want_pallas():
+            from .pallas_gf import apply_matrix_pallas
 
-        from ..common.device_policy import get_device_policy
+            from ..common.device_policy import get_device_policy
 
-        # interpret mode only off the chip (CEPH_TPU_EC_KERNEL=pallas on
-        # a CPU host); "tpu" is the one backend name that compiles it
-        return apply_matrix_pallas(
-            mat, chunks,
-            interpret=get_device_policy().backend() != "tpu",
-        ), "pallas"
-    if donate:
-        # only take the donated jit where the backend honors donation
-        # (CPU accepts-and-ignores it, with a warning per shape): the
-        # non-donating path is byte-identical, so nothing is lost
-        from .device_pool import donation_supported
+            # interpret mode only off the chip (CEPH_TPU_EC_KERNEL=pallas
+            # on a CPU host); "tpu" is the one backend name that compiles
+            return apply_matrix_pallas(
+                mat, chunks,
+                interpret=get_device_policy().backend() != "tpu",
+            ), "pallas"
+        if donate:
+            # only take the donated jit where the backend honors donation
+            # (CPU accepts-and-ignores it, with a warning per shape): the
+            # non-donating path is byte-identical, so nothing is lost
+            from .device_pool import donation_supported
 
-        if donation_supported():
-            B = _bitmatrix_for(mat, mat_key)
-            return _apply_bitmatrix_donated(
-                B, jnp.asarray(chunks, dtype=jnp.uint8)), "xla"
-    return apply_matrix_xla(mat, chunks, mat_key=mat_key), "xla"
+            if donation_supported():
+                B = _bitmatrix_for(mat, mat_key)
+                return _apply_bitmatrix_donated(
+                    B, jnp.asarray(chunks, dtype=jnp.uint8)), "xla"
+        return apply_matrix_xla(mat, chunks, mat_key=mat_key), "xla"
 
 
 def apply_matrix_dev(mat: np.ndarray, chunks, mat_key: str | None = None,
@@ -471,7 +474,8 @@ class BitplaneCodec:
 
     def encode(self, data) -> jnp.ndarray:
         """[k, L] data shards -> [m, L] parity shards (device array)."""
-        data = jnp.asarray(data, dtype=jnp.uint8)
+        with stage("gf.h2d"):
+            data = jnp.asarray(data, dtype=jnp.uint8)
         if data.shape[0] != self.k:
             raise ValueError(f"expected {self.k} data shards, got {data.shape[0]}")
         return apply_matrix_jax(self.coding, data,
@@ -486,8 +490,10 @@ class BitplaneCodec:
         key = tuple(available_rows[: self.k])
         ent = self._decode_cache.get(key)
         if ent is None:
-            dm = decode_matrix_for(self.generator, self.k, list(key)).astype(np.uint8)
-            ent = (dm, matrix_digest(dm))
+            with stage("ec.decode_matrix"):
+                dm = decode_matrix_for(self.generator, self.k,
+                                       list(key)).astype(np.uint8)
+                ent = (dm, matrix_digest(dm))
             self._decode_cache[key] = ent
         return ent
 
@@ -500,7 +506,8 @@ class BitplaneCodec:
         if len(rows) < self.k:
             raise ValueError(f"need >= {self.k} shards, got {len(rows)}")
         dm, dm_key = self._decode_entry(rows)
-        shards = jnp.asarray(shards, dtype=jnp.uint8)[: self.k]
+        with stage("gf.h2d"):
+            shards = jnp.asarray(shards, dtype=jnp.uint8)[: self.k]
         return apply_matrix_jax(dm, shards, mat_key=dm_key)
 
     def reconstruct(self, available_rows, shards, want_rows) -> jnp.ndarray:

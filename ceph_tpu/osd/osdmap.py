@@ -29,6 +29,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from ..common.tracer import stage
 from ..crush import CrushWrapper, ITEM_NONE
 from ..crush.hash import crush_hash32_2_np
 
@@ -336,18 +337,27 @@ class OSDMap:
         crush_do_rule_batch; the sparse upmap/temp overrides and the up/
         affinity filters are cheap vectorized host post-passes, exactly the
         split SURVEY.md §3.3 prescribes for the batch consumers."""
-        pool = self.pools[pool_id]
-        ps = np.arange(pool.pg_num, dtype=np.uint32)
-        pps = pool.raw_pg_to_pps_batch(ps)
-        raw = np.asarray(
-            self.crush.do_rule_batch(
+        with stage("osdmap.map_pool"):
+            pool = self.pools[pool_id]
+            ps = np.arange(pool.pg_num, dtype=np.uint32)
+            pps = pool.raw_pg_to_pps_batch(ps)
+            out = self.crush.do_rule_batch(
                 pool.crush_rule,
                 pps.astype(np.int32),
                 pool.size,
                 self.osd_weight,
             )
-        ).astype(np.int64)
+            with stage("crush.wait"):
+                out.block_until_ready()
+            with stage("crush.d2h"):
+                raw = np.asarray(out).astype(np.int64)
+            with stage("osdmap.post_pass"):
+                return self._post_pass(pool_id, pool, pps, raw)
 
+    def _post_pass(self, pool_id: int, pool: PGPool, pps: np.ndarray,
+                   raw: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+        """map_pool's host post-pass over the raw CRUSH rows: upmap
+        overrides, the up filter, the up primaries."""
         # sparse per-PG upmap overrides (dict-sized, not pg_num-sized work)
         for (pid, s), forced in self.pg_upmap.items():
             if (

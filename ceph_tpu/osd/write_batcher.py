@@ -78,7 +78,7 @@ from ..common.failpoint import FailpointCrash, failpoint
 from ..common.kernel_telemetry import TELEMETRY
 from ..common.lockdep import make_lock
 from ..common.throttle import Throttle
-from ..common.tracer import TRACER, kernel_annotation, op_trace, trace_now
+from ..common.tracer import TRACER, op_trace, stage, trace_now
 
 
 class _FlushRef:
@@ -546,7 +546,7 @@ class WriteBatcher:
         if self._logger is not None:
             self._logger.inc("ec_batch_inline")
         t0 = trace_now()
-        with kernel_annotation(
+        with stage(
             "ec_encode_inline", (tctx.trace_id,) if tctx is not None else ()
         ):
             parity = np.asarray(  # noqa: CL8 — inline per-op encode is deliberately synchronous

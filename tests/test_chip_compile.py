@@ -6,19 +6,48 @@ VMEM, tiling, a program that does not fit the device.  Here the TPU
 compiler that ships with libtpu compiles each kernel of the main path at
 the widths and layouts the code picks, for a chip that is described and
 not attached.  Nothing runs, so this says nothing about results or time;
-chip_smoke.py runs the same kernels on the chip.
+chip_smoke.py runs the same kernels on the chip.  The kernels keep the
+names the benchmark's per-layer readers find them by in a device trace
+(the `KERNEL` pattern of benchmark/layer_metrics/gf_kernel_roofline.py
+and straw2_ns_per_map.py): a rename would leave those metrics nothing to
+read.
 
 The topology is described only inside the module fixture: only one
 process may load libtpu at a time, and a test worker that loads it at
 import would leave the others unable to collect this file.
 """
+import ast
 import os
+import re
 import warnings
 
 import numpy as np
 import pytest
 
 CHIP_HBM_BYTES = 16 << 30  # TPU v5e
+READERS = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark", "layer_metrics")
+
+
+def _kernel_pattern(reader: str) -> str:
+    """The `KERNEL` name pattern of benchmark/layer_metrics/<reader>.py,
+    read from the file (the reader imports the benchmark's modules)."""
+    with open(os.path.join(READERS, reader + ".py")) as f:
+        tree = ast.parse(f.read())
+    for node in tree.body:
+        if isinstance(node, ast.Assign) and any(
+                isinstance(t, ast.Name) and t.id == "KERNEL"
+                for t in node.targets):
+            return ast.literal_eval(node.value)
+    raise LookupError(f"no KERNEL in {reader}.py")
+
+
+def _assert_named_for(reader: str, hlo_text: str) -> None:
+    """Some instruction of the compiled program matches the pattern the
+    reader looks for among the device trace's ops."""
+    rx = re.compile(_kernel_pattern(reader))
+    assert any(rx.search(line) for line in hlo_text.splitlines()), (
+        f"no instruction matches {reader}'s KERNEL {rx.pattern!r}")
 
 
 @pytest.fixture(scope="module")
@@ -117,6 +146,7 @@ def test_gf_kernel_compiles(one_chip, matrix):
         rows=rows, n=n, G=G, tile=tile, interpret=False,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    _assert_named_for("gf_kernel_roofline", compiled.as_text())
 
 
 def test_straw2_scorer_compiles(one_chip):
@@ -133,6 +163,7 @@ def test_straw2_scorer_compiles(one_chip):
         tile=DEFAULT_TILE, interpret=False,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    _assert_named_for("straw2_ns_per_map", compiled.as_text())
 
 
 def test_fused_write_flush_compiles(one_chip):
@@ -179,3 +210,5 @@ def test_limb_rule_fn_compiles(one_chip, monkeypatch):
         _spec((1024,), jnp.int32, one_chip),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # the scorer as the crush cell's trace shows it: inside the rule fn
+    _assert_named_for("straw2_ns_per_map", compiled.as_text())
