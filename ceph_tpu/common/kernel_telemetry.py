@@ -241,6 +241,25 @@ class KernelTelemetry:
         if resident_bytes is not None:
             self.perf.set("device_pool_resident_bytes", int(resident_bytes))
 
+    def record_rebuild_matrix(self, hit: bool) -> None:
+        """One lookup of an RS codec's rebuild-matrix cache
+        (ops/bitplane.py `BitplaneCodec.reconstruct`), mirrored into the
+        shared PerfCounters as `rebuild_matrix_hits`/`_misses`; the codec
+        keeps its own totals."""
+        if not self.enabled:
+            return
+        if "rebuild_matrix_hits" not in self._declared:
+            with self._lock:
+                if "rebuild_matrix_hits" not in self._declared:
+                    for name in ("hits", "misses"):
+                        self.perf._add(
+                            f"rebuild_matrix_{name}", "u64",
+                            f"RS decode rebuild-matrix cache {name} "
+                            f"(ops/bitplane.py; docs/tracing.md)")
+                    self._declared.add("rebuild_matrix_hits")
+        self.perf.inc("rebuild_matrix_hits" if hit
+                      else "rebuild_matrix_misses")
+
     # -- fallback latches + event log --------------------------------------
     def record_event(self, kind: str, **fields) -> None:
         """Append one transition event (fallback latch/clear, sentinel

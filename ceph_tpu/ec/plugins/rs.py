@@ -127,26 +127,46 @@ class RSCodec(ErasureCode):
         if len(avail) < self.k:
             raise InsufficientChunks(f"need {self.k}, have {len(avail)}")
         use = avail[: self.k]
+        want = sorted(set(want_to_read))
+        missing = [w for w in want if w not in chunks]
         with stage("ec.stack"):
             shards = np.stack([np.asarray(chunks[r], dtype=np.uint8)
                                for r in use])
         if self.backend == "jax":
-            # cephdma: the gathered helper stack commits to the device
-            # through the stripe pool (recovery's _rebuild_shard_chunk
-            # and degraded reads both land here), so repeated rebuilds
-            # of one geometry recycle buffers instead of allocating
-            from ...ops.device_pool import POOL
+            result = self._rebuild_on_device(use, shards, missing)
+        else:
+            result = self._rebuild_on_host(use, shards, missing)
+        for wanted in want:
+            if wanted in chunks:
+                result[wanted] = np.asarray(chunks[wanted], dtype=np.uint8)
+        return result
 
-            with stage("gf.h2d"):
-                dev = POOL.put(shards) if POOL.enabled() else shards
-            try:
-                data = _to_host(self._jax_codec.decode(use, dev))
-            finally:
-                # a decode failure (bad shard set, kernel abort) must
-                # not strand the pooled stripe buffer
-                if dev is not shards:
-                    POOL.release(dev)
-        elif self.backend == "oracle":
+    def _rebuild_on_device(self, use, shards, missing) -> dict:
+        """Only the missing shards, in one apply over the survivors and
+        one fetch of [len(missing), L]."""
+        if not missing:
+            return {}
+        # cephdma: the gathered helper stack commits to the device
+        # through the stripe pool (recovery's _rebuild_shard_chunk and
+        # degraded reads both land here), so repeated rebuilds of one
+        # geometry recycle buffers instead of allocating
+        from ...ops.device_pool import POOL
+
+        with stage("gf.h2d"):
+            dev = POOL.put(shards) if POOL.enabled() else shards
+        try:
+            rebuilt = _to_host(self._jax_codec.reconstruct(use, dev, missing))
+        finally:
+            # a decode failure (bad shard set, kernel abort) must not
+            # strand the pooled stripe buffer
+            if dev is not shards:
+                POOL.release(dev)
+        return dict(zip(missing, rebuilt))
+
+    def _rebuild_on_host(self, use, shards, missing) -> dict:
+        """The referee backends: all k data rows, then the missing parity
+        re-encoded from them."""
+        if self.backend == "oracle":
             from ... import native_oracle
 
             data = native_oracle.decode(self.coding, self.k, use, shards)
@@ -155,36 +175,15 @@ class RSCodec(ErasureCode):
 
             out = np_decode(self.coding, self.k, dict(zip(use, shards)), want=list(range(self.k)))
             data = np.stack([out[i] for i in range(self.k)])
-        result: dict[int, np.ndarray] = {}
-        missing_par = [
-            w for w in sorted(set(want_to_read))
-            if w >= self.k and w not in chunks
-        ]
+        result = {w: data[w] for w in missing if w < self.k}
+        missing_par = [w for w in missing if w >= self.k]
         if missing_par:
-            # one batched apply for every missing parity row (device-path
-            # when backend is jax, host referee otherwise)
+            from ...gf.reference_codec import apply_matrix
+
             rowmat = np.ascontiguousarray(
                 self.coding[[w - self.k for w in missing_par]]
             )
-            if self.backend == "jax":
-                import jax.numpy as jnp
-
-                from ...ops.bitplane import apply_matrix_jax
-
-                with stage("gf.h2d"):
-                    dev_data = jnp.asarray(data)
-                par = _to_host(apply_matrix_jax(rowmat, dev_data))
-            else:
-                from ...gf.reference_codec import apply_matrix
-
-                par = apply_matrix(rowmat, data)
-            for i, w in enumerate(missing_par):
-                result[w] = par[i]
-        for wanted in sorted(set(want_to_read)):
-            if wanted in chunks:
-                result[wanted] = np.asarray(chunks[wanted], dtype=np.uint8)
-            elif wanted < self.k:
-                result[wanted] = data[wanted]
+            result.update(zip(missing_par, apply_matrix(rowmat, data)))
         return result
 
 

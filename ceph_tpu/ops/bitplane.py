@@ -37,6 +37,7 @@ import numpy as np
 from ..common.kernel_telemetry import SENTINEL, TELEMETRY
 from ..common.tracer import stage
 from ..gf.matrix import decode_matrix_for, matrix_to_bitmatrix, systematic_generator
+from ..gf.tables import gf_matmul
 
 _BIT_IDX = np.arange(8, dtype=np.uint8)
 
@@ -471,6 +472,12 @@ class BitplaneCodec:
         #: erasure pattern -> (decode matrix, its stable digest)
         self._decode_cache: dict[tuple[int, ...],
                                  tuple[np.ndarray, str]] = {}
+        #: (survivors, wanted rows) -> (rebuild matrix, its digest)
+        self._rebuild_cache: dict[tuple[tuple[int, ...], tuple[int, ...]],
+                                  tuple[np.ndarray, str]] = {}
+        self.rebuild_matrix_hits = 0
+        self.rebuild_matrix_misses = 0
+        self._count_lock = Lock()
 
     def encode(self, data) -> jnp.ndarray:
         """[k, L] data shards -> [m, L] parity shards (device array)."""
@@ -510,11 +517,38 @@ class BitplaneCodec:
             shards = jnp.asarray(shards, dtype=jnp.uint8)[: self.k]
         return apply_matrix_jax(dm, shards, mat_key=dm_key)
 
+    def _rebuild_entry(self, available_rows, want_rows) -> tuple[np.ndarray, str]:
+        key = (tuple(available_rows[: self.k]), tuple(want_rows))
+        ent = self._rebuild_cache.get(key)
+        with self._count_lock:
+            if ent is None:
+                self.rebuild_matrix_misses += 1
+            else:
+                self.rebuild_matrix_hits += 1
+        TELEMETRY.record_rebuild_matrix(hit=ent is not None)
+        if ent is not None:
+            return ent
+        with stage("ec.decode_matrix"):
+            dm = decode_matrix_for(self.generator, self.k, list(key[0]))
+            rm = gf_matmul(self.generator[list(key[1])], dm)
+            ent = (rm, matrix_digest(rm))
+        self._rebuild_cache[key] = ent
+        return ent
+
     def reconstruct(self, available_rows, shards, want_rows) -> jnp.ndarray:
-        """Rebuild arbitrary shards (data or parity) — the recovery path
-        (reference: src/osd/ECBackend.cc :: recover_object re-encodes missing
-        shards from decoded data)."""
-        data = self.decode(available_rows, shards)
-        want_rows = list(int(w) for w in want_rows)
-        out_mat = self.generator[want_rows, :].astype(np.uint8)
-        return apply_matrix_jax(out_mat, data)
+        """Rebuild exactly `want_rows` (data or parity shard ids) from >= k
+        surviving shards: [len(want_rows), L] on the device, in one apply.
+
+        Each row of the rebuild matrix is generator[w] times the inverse
+        of the survivors' generator rows: row w of the inverse for a data
+        shard, coding[w - k] times it for a parity shard (reference:
+        src/erasure-code/isa/ErasureCodeIsa.cc ::
+        ErasureCodeIsaDefault::isa_decode), cached per pattern.
+        """
+        rows = tuple(int(r) for r in available_rows)
+        if len(rows) < self.k:
+            raise ValueError(f"need >= {self.k} shards, got {len(rows)}")
+        rm, rm_key = self._rebuild_entry(rows, [int(w) for w in want_rows])
+        with stage("gf.h2d"):
+            shards = jnp.asarray(shards, dtype=jnp.uint8)[: self.k]
+        return apply_matrix_jax(rm, shards, mat_key=rm_key)

@@ -67,8 +67,63 @@ def test_reconstruct_parity_shards():
     # lose data shard 3 and parity shard k+1; rebuild both from the rest
     avail = [i for i in range(k + m) if i not in (3, k + 1)]
     rebuilt = np.asarray(codec.reconstruct(avail, shards[avail], [3, k + 1]))
+    assert rebuilt.shape == (2, 512)
     np.testing.assert_array_equal(rebuilt[0], data[3])
     np.testing.assert_array_equal(rebuilt[1], parity[1])
+    assert (codec.rebuild_matrix_hits, codec.rebuild_matrix_misses) == (0, 1)
+    # the same pattern again is a hit; other wanted rows are a new matrix
+    codec.reconstruct(avail, shards[avail], [3, k + 1])
+    only = np.asarray(codec.reconstruct(avail, shards[avail], [k + 1]))
+    np.testing.assert_array_equal(only, parity[1:2])
+    assert (codec.rebuild_matrix_hits, codec.rebuild_matrix_misses) == (1, 2)
+
+
+def _rs84_pair():
+    from ceph_tpu.ec.plugins.rs import RSCodec
+
+    profile = {"k": "8", "m": "4", "technique": "cauchy_good"}
+    return RSCodec(profile, backend="jax"), RSCodec(profile, backend="numpy")
+
+
+@pytest.mark.parametrize("e", [1, 2, 3, 4])
+def test_rs_decode_every_pattern_matches_numpy(e):
+    """Every pattern of e lost shards of RS(8,4): the device codec's
+    one-apply rebuild equals the numpy referee byte for byte."""
+    from itertools import combinations
+
+    dev, ref = _rs84_pair()
+    rng = np.random.default_rng(40 + e)
+    data = rng.integers(0, 256, (8, 512), dtype=np.uint8)
+    shards = np.vstack([data, ref.encode_chunks(data)])
+    for lost in combinations(range(12), e):
+        have = {i: shards[i] for i in range(12) if i not in lost}
+        got = dev.decode(set(lost), have, 512)
+        want = ref.decode(set(lost), have, 512)
+        assert sorted(got) == sorted(want) == list(lost)
+        for i in lost:
+            assert np.asarray(got[i]).tobytes() == want[i].tobytes() \
+                == shards[i].tobytes()
+
+
+def test_rs_decode_wanted_subset_of_lost():
+    """Asked for two of four lost shards, the codec rebuilds and returns
+    those two, through a two-row rebuild matrix."""
+    dev, ref = _rs84_pair()
+    rng = np.random.default_rng(44)
+    data = rng.integers(0, 256, (8, 512), dtype=np.uint8)
+    shards = np.vstack([data, ref.encode_chunks(data)])
+    lost = (2, 6, 9, 10)
+    have = {i: shards[i] for i in range(12) if i not in lost}
+    got = dev.decode({6, 10}, have, 512)
+    assert sorted(got) == [6, 10]
+    for i in got:
+        assert got[i].tobytes() == shards[i].tobytes()
+    use = sorted(have)
+    rows = dev._jax_codec.reconstruct(use, np.stack([have[i] for i in use]),
+                                      [6, 10])
+    assert rows.shape == (2, 512)
+    assert (dev._jax_codec.rebuild_matrix_hits,
+            dev._jax_codec.rebuild_matrix_misses) == (1, 1)
 
 
 def test_decode_matrix_cache_hit():
