@@ -246,19 +246,32 @@ class KernelTelemetry:
         (ops/bitplane.py `BitplaneCodec.reconstruct`), mirrored into the
         shared PerfCounters as `rebuild_matrix_hits`/`_misses`; the codec
         keeps its own totals."""
+        self._record_lookup("rebuild_matrix", hit,
+                            "RS decode rebuild-matrix cache",
+                            "ops/bitplane.py")
+
+    def record_repair_matrix(self, hit: bool) -> None:
+        """The Clay twin of record_rebuild_matrix: one lookup of the
+        single-shard repair-matrix cache (ec/plugins/clay.py
+        `ClayCodec.repair_matrix_entry`), as `repair_matrix_hits`/
+        `_misses`."""
+        self._record_lookup("repair_matrix", hit,
+                            "Clay repair-matrix cache",
+                            "ec/plugins/clay.py")
+
+    def _record_lookup(self, prefix: str, hit: bool, what: str,
+                       where: str) -> None:
         if not self.enabled:
             return
-        if "rebuild_matrix_hits" not in self._declared:
+        if f"{prefix}_hits" not in self._declared:
             with self._lock:
-                if "rebuild_matrix_hits" not in self._declared:
+                if f"{prefix}_hits" not in self._declared:
                     for name in ("hits", "misses"):
                         self.perf._add(
-                            f"rebuild_matrix_{name}", "u64",
-                            f"RS decode rebuild-matrix cache {name} "
-                            f"(ops/bitplane.py; docs/tracing.md)")
-                    self._declared.add("rebuild_matrix_hits")
-        self.perf.inc("rebuild_matrix_hits" if hit
-                      else "rebuild_matrix_misses")
+                            f"{prefix}_{name}", "u64",
+                            f"{what} {name} ({where}; docs/tracing.md)")
+                    self._declared.add(f"{prefix}_hits")
+        self.perf.inc(f"{prefix}_hits" if hit else f"{prefix}_misses")
 
     # -- fallback latches + event log --------------------------------------
     def record_event(self, kind: str, **fields) -> None:

@@ -112,6 +112,10 @@ DEVICE_STAGES = (
     "gf.dispatch",        # kron lookup, matrix commits, pad, launch
     "gf.wait",            # block on a GF apply's result
     "gf.d2h",             # fetch of the ready result to the host
+    "clay.repair",        # ClayCodec._repair, the whole call
+    "clay.repair_matrix",  # repair-matrix build on a cache miss
+    "clay.gather",        # helper buffers -> plane-major apply input
+    "clay.scatter",       # apply output -> stripe-major chunk bytes
     "osdmap.map_pool",    # OSDMap.map_pool, the whole call
     "osdmap.post_pass",   # upmap overrides, up filter, primaries
     "crush.launch",       # xs/weight commit and each rule-fn dispatch

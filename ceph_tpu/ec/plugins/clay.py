@@ -23,22 +23,54 @@ Construction (Clay codes, FAST'18, as the reference implements):
   the q^(t-1) planes with z_{y0} = x0 from every survivor — bandwidth
   d/(k*q) of naive (BASELINE.json config 4 measures exactly this).
 
+Profile: `k`, `m`, `d`, `scalar_mds` and `technique`, as upstream's.
+The inner scalar MDS code is jerasure's (`scalar_mds=jerasure`, the only
+one built here), its coding matrix from the RS plugin's own
+`build_coding_matrix(technique, k, m)`; `technique` defaults to
+reed_sol_van, as upstream.
+
+Repair through `decode` (upstream's `is_repair` branch of
+ErasureCodeClay::decode, looped over stripes as ECUtil::decode does):
+each helper buffer may hold only that helper's repair-plane sub-chunks,
+`chunk_size / q` bytes a stripe, stripe after stripe; any number of
+stripes is rebuilt in one apply of the cached [Z, d*Z/q] repair matrix.
+The helpers' stripe-major `[stripe, plane, sub_len]` bytes are laid out
+on the device as the apply's plane-major rows (`clay.gather`), and its
+output as stripe-major chunk bytes before the fetch (`clay.scatter`):
+on the v5e host a numpy transpose of those 88 MiB took ~100 ms, the
+device relayout a few.
+
 Scope notes vs the reference: d must satisfy q | (k+m) (the reference pads
 with shortened virtual nodes otherwise); bandwidth-optimal repair is
 implemented for d = k+m-1 with all survivors as helpers, and falls back to
-full decode for other cases.  Parity bytes are internally defined (empty
-reference mount, SURVEY.md §0); sub-chunk accounting and repair-bandwidth
-semantics are what tests pin.
+full decode for other cases.  The layout the OSD writes is one stripe an
+object (`ec_backend._ec_encode`); upstream's 4 KiB stripe unit, with 64 B
+sub-chunks and a codeword a stripe, is not laid out here.  Parity bytes
+are internally defined (empty reference mount, SURVEY.md §0): upstream
+builds its pairwise transform from a (2,2) jerasure code, so parity is
+not claimed byte-identical with upstream; sub-chunk accounting and
+repair-bandwidth semantics are what tests pin.
 """
 from __future__ import annotations
 
+import threading
+from functools import partial
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-from ...gf.matrix import decode_matrix_for, systematic_generator, vandermonde_coding_matrix
-
+from ...common.kernel_telemetry import TELEMETRY
+from ...common.tracer import stage
+from ...gf.matrix import decode_matrix_for, systematic_generator
 from ...gf.tables import GF_MUL_TABLE, gf_inv
 from ..interface import ErasureCode, InsufficientChunks, InvalidProfile
 from ..registry import ErasureCodePlugin
+from .rs import _to_host, build_coding_matrix
+
+#: inner scalar MDS code families upstream's `scalar_mds` names that are
+#: built here
+SCALAR_MDS = ("jerasure",)
 
 GAMMA = 2
 _INV_DET = gf_inv(1 ^ GF_MUL_TABLE[GAMMA, GAMMA])  # 1/(1 + g^2)
@@ -53,6 +85,13 @@ class ClayCodec(ErasureCode):
     def __init__(self, profile: dict | None = None):
         #: (lost, helpers) -> (repair matrix, stable digest)
         self._repair_mat_cache: dict[tuple, tuple[np.ndarray, str]] = {}
+        self._count_lock = threading.Lock()
+        #: repair-matrix cache lookups, and the repair's helper bytes in
+        #: and rebuilt bytes out
+        self.repair_matrix_hits = 0
+        self.repair_matrix_misses = 0
+        self.repair_bytes_in = 0
+        self.repair_bytes_out = 0
         super().__init__(profile)
 
     def init(self, profile: dict) -> None:
@@ -74,9 +113,14 @@ class ClayCodec(ErasureCode):
             )
         self.t = n // self.q
         self.sub_chunk_count = self.q**self.t
-        coding = vandermonde_coding_matrix(self.k, self.m)
-        self.generator = systematic_generator(coding)
-        self.coding = coding.astype(np.uint8)
+        self.scalar_mds = profile.get("scalar_mds", "jerasure")
+        if self.scalar_mds not in SCALAR_MDS:
+            raise InvalidProfile(
+                f"scalar_mds={self.scalar_mds!r} unsupported; known: "
+                f"{SCALAR_MDS}")
+        self.technique = profile.get("technique", "reed_sol_van")
+        self.coding = build_coding_matrix(self.technique, self.k, self.m)
+        self.generator = systematic_generator(self.coding)
 
     def get_sub_chunk_count(self) -> int:
         return self.sub_chunk_count
@@ -191,6 +235,45 @@ class ClayCodec(ErasureCode):
             [parity[self.k + i].reshape(L) for i in range(self.m)]
         )
 
+    def decode(self, want_to_read, chunks, chunk_size):
+        """One lost shard with d = k+m-1 helpers is repaired (upstream's
+        `is_repair` branch), from whole chunks or from the helpers'
+        repair-plane sub-chunks alone; anything else decodes whole chunks.
+
+        `chunk_size` is one stripe's chunk.  A buffer of sub-chunks holds
+        `chunk_size / q` bytes a stripe, for any number of stripes, stripe
+        after stripe: the ranges `minimum_to_decode` returns, read once a
+        stripe.  The rebuilt shard comes back stripe after stripe.  A
+        buffer of exactly `chunk_size` bytes is read as a whole chunk, so
+        q stripes of sub-chunks read as one whole chunk: give them in two
+        calls."""
+        want = set(want_to_read)
+        missing = want - set(chunks)
+        stripes = self._repair_stripes(missing, chunks, chunk_size)
+        if stripes is None:
+            return super().decode(want, chunks, chunk_size)
+        (lost,) = missing
+        out = {w: np.asarray(chunks[w], dtype=np.uint8) for w in want - missing}
+        out[lost] = self._repair(lost, chunks, chunk_size, stripes)
+        return out
+
+    def _repair_stripes(self, missing, chunks, chunk_size) -> int | None:
+        """Stripes of repair-plane sub-chunks in each helper buffer where
+        `decode` can repair from them, else None."""
+        Z = self.sub_chunk_count
+        if (len(missing) != 1 or self.d != self.k + self.m - 1
+                or len(chunks) < self.d or chunk_size <= 0
+                or chunk_size % Z):
+            return None
+        lengths = {len(v) for v in chunks.values()}
+        if len(lengths) != 1:
+            return None
+        (length,) = lengths
+        part = chunk_size // self.q
+        if length in (0, chunk_size) or length % part:
+            return None  # whole chunks go through decode_chunks
+        return length // part
+
     def decode_chunks(self, want_to_read, chunks):
         have = {int(i): np.asarray(v, dtype=np.uint8) for i, v in chunks.items()}
         L = len(next(iter(have.values())))
@@ -201,8 +284,7 @@ class ClayCodec(ErasureCode):
         if not lost_wanted:
             return {w: have[w] for w in want_to_read}
         if len(erased) == 1 and self.d == self.k + self.m - 1 and len(have) >= self.d:
-            rebuilt = self._repair_one(have, erased[0], sub_len)
-            out = {erased[0]: rebuilt}
+            out = {erased[0]: self._repair(erased[0], have, L, 1)}
         else:
             C = {i: v.reshape(Z, sub_len) for i, v in have.items()}
             dec = self._layered_decode(C, erased, sub_len)
@@ -278,12 +360,19 @@ class ClayCodec(ErasureCode):
         ``M.tobytes()`` host copy per rebuilt chunk (cephdma)."""
         key = (lost, helpers)
         cached = self._repair_mat_cache.get(key)
+        with self._count_lock:
+            if cached is None:
+                self.repair_matrix_misses += 1
+            else:
+                self.repair_matrix_hits += 1
+        TELEMETRY.record_repair_matrix(hit=cached is not None)
         if cached is not None:
             return cached
-        M = self._build_repair_matrix(lost, helpers)
         from ...ops.bitplane import matrix_digest
 
-        ent = (M, matrix_digest(M))
+        with stage("clay.repair_matrix"):
+            M = self._build_repair_matrix(lost, helpers)
+            ent = (M, matrix_digest(M))
         self._repair_mat_cache[key] = ent
         return ent
 
@@ -357,26 +446,75 @@ class ClayCodec(ErasureCode):
         helpers: tuple[int, ...],
     ) -> np.ndarray:
         """[len(helpers)*nB, sub_len] — each helper's repair-plane
-        sub-chunks stacked in helper order (the layout repair_matrix
-        contracts over)."""
-        Z = self.sub_chunk_count
+        sub-chunks of one stripe stacked in helper order (the layout
+        repair_matrix contracts over); a buffer holds the whole chunk or
+        only its repair planes."""
         planes = np.asarray(self.repair_planes(lost))
-        return np.concatenate(
-            [have[n].reshape(Z, sub_len)[planes] for n in helpers]
-        )
+        rows = [np.asarray(have[n], dtype=np.uint8).reshape(-1, sub_len)
+                for n in helpers]
+        return np.concatenate([r[planes] if r.shape[0] == self.sub_chunk_count
+                               else r for r in rows])
 
-    def _repair_one(
-        self, have: dict[int, np.ndarray], lost: int, sub_len: int
-    ) -> np.ndarray:
-        """Rebuild `lost` reading only the repair planes from all
-        survivors: one cached-matrix device apply."""
+    def _repair(self, lost: int, have: dict[int, np.ndarray],
+                chunk_size: int, stripes: int) -> np.ndarray:
+        """Rebuild `lost`, `stripes` stripes of it, from the repair planes
+        of all survivors: one cached-matrix device apply over every
+        stripe's columns.  Several stripes are laid out on the device,
+        around the apply: the helpers' stripe-major rows as its
+        plane-major input, its output as stripe-major chunk bytes."""
         from ...ops.bitplane import apply_matrix_jax
 
-        helpers = tuple(sorted(have))
-        M, m_key = self.repair_matrix_entry(lost, helpers)
-        x = self.gather_repair_input(have, lost, sub_len, helpers)
-        out = np.asarray(apply_matrix_jax(M, x, mat_key=m_key))
-        return out.reshape(self.sub_chunk_count * sub_len)
+        sub_len = chunk_size // self.sub_chunk_count
+        with stage("clay.repair"):
+            helpers = tuple(sorted(have))
+            M, m_key = self.repair_matrix_entry(lost, helpers)
+            with stage("clay.gather"):
+                if stripes == 1:
+                    # one stripe's rows are plane-major once stacked, and
+                    # the host stack compiles nothing for each new size
+                    x = self.gather_repair_input(have, lost, sub_len, helpers)
+                    with stage("gf.h2d"):
+                        x = jax.device_put(x)
+                else:
+                    with stage("gf.h2d"):
+                        rows = jax.device_put(
+                            [np.asarray(have[h], dtype=np.uint8).reshape(
+                                -1, sub_len) for h in helpers])
+                    x = _plane_major(tuple(rows), stripes)
+            out = apply_matrix_jax(M, x, mat_key=m_key)
+            if stripes > 1:
+                with stage("clay.scatter"):
+                    out = _stripe_major(out, stripes)
+            chunk = _to_host(out).reshape(stripes * chunk_size)
+        with self._count_lock:
+            self.repair_bytes_in += sum(
+                int(np.asarray(v).nbytes) for v in have.values())
+            self.repair_bytes_out += chunk.nbytes
+        return chunk
+
+
+# The device relayouts are column slices and concatenates: a reshape that
+# splits or merges the minor dimension of a uint8 device array compiles
+# for minutes on v5e (ops/pallas_gf.py).
+@partial(jax.jit, static_argnums=1)
+def _plane_major(rows, stripes: int):
+    """Each helper's stripe-major [stripes*nB, sub_len] rows -> the
+    apply's [helpers*nB, stripes*sub_len] input: row (helper, plane)
+    holds that plane's sub-chunk of every stripe, stripe after stripe."""
+    nB = rows[0].shape[0] // stripes
+    return jnp.concatenate([
+        jnp.concatenate([r[s * nB:(s + 1) * nB] for s in range(stripes)],
+                        axis=1)
+        for r in rows])
+
+
+@partial(jax.jit, static_argnums=1)
+def _stripe_major(out, stripes: int):
+    """The apply's [Z, stripes*sub_len] output -> [stripes*Z, sub_len]:
+    stripe after stripe, each stripe's chunk bytes."""
+    sub_len = out.shape[1] // stripes
+    return jnp.concatenate([out[:, s * sub_len:(s + 1) * sub_len]
+                            for s in range(stripes)])
 
 
 class ClayPlugin(ErasureCodePlugin):

@@ -1127,17 +1127,14 @@ class RecoveryMixin:
                              size):
         """CLAY plan: fetch only the repair-plane sub-chunk ranges from
         each of the d helpers (ranged MECSubOpRead — hinfo-verified
-        server-side) and rebuild through the codec's cached repair
-        matrix: the live d/q-of-a-chunk repair bandwidth the bench
-        measured offline, now on the recovery path."""
-        if not hasattr(codec, "repair_matrix"):
-            return None
+        server-side) and rebuild through the codec's own `decode`, which
+        repairs from those sub-chunks: the live d/q-of-a-chunk repair
+        bandwidth, on the recovery path."""
         Z = codec.get_sub_chunk_count()
         chunk_size = len(mine)
         if Z <= 1 or chunk_size % Z:
             return None
         sub_len = chunk_size // Z
-        nB = len(codec.repair_planes(lost))
         fetched: dict[int, np.ndarray] = {}
         bytes_read = 0
         for h in helpers:
@@ -1161,41 +1158,14 @@ class RecoveryMixin:
                     target = ver
                 elif ver != target:
                     return None  # stale-generation helper: fall back
-            rows = np.frombuffer(buf, np.uint8).reshape(-1, sub_len)
-            if rows.shape[0] not in (nB, Z):
-                return None
-            if rows.shape[0] == Z:
-                # a full-chunk helper (want&avail merge case): slice
-                # its repair planes for the stacked input
-                rows = rows[np.asarray(codec.repair_planes(lost))]
-            fetched[h] = rows
+            fetched[h] = np.frombuffer(buf, np.uint8)
             bytes_read += want_len
         try:
-            from ..ops.bitplane import apply_matrix_jax
-            from ..ops.device_pool import POOL
-
-            # cephdma: the cached repair matrix's stable digest keys the
-            # device bitmatrix cache (no per-rebuild M.tobytes() host
-            # copy), and the gathered helper sub-chunks commit to the
-            # device through the stripe pool so repeated rebuilds of
-            # one geometry recycle the same buffers
-            if hasattr(codec, "repair_matrix_entry"):
-                M, m_key = codec.repair_matrix_entry(lost, tuple(helpers))
-            else:
-                M, m_key = codec.repair_matrix(lost, tuple(helpers)), None
-            x = np.concatenate([fetched[h] for h in helpers])
-            x_dev = POOL.put(x) if POOL.enabled() else x
-            try:
-                out = np.asarray(apply_matrix_jax(M, x_dev, mat_key=m_key),
-                                 np.uint8)
-            finally:
-                # the except below swallows apply failures into a None
-                # result — the pooled sub-chunk buffer must still come
-                # back or every failed rebuild shrinks the pool
-                if x_dev is not x:
-                    POOL.release(x_dev)
-            chunk = out.reshape(Z * sub_len).tobytes()
+            chunk = np.asarray(codec.decode({lost}, fetched, chunk_size)[lost],
+                               np.uint8).tobytes()
         except Exception:
+            return None
+        if len(chunk) != chunk_size:
             return None
         return chunk, size, len(helpers), bytes_read
 

@@ -149,6 +149,26 @@ def test_gf_kernel_compiles(one_chip, matrix):
     _assert_named_for("gf_kernel_roofline", compiled.as_text())
 
 
+def test_clay_relayouts_compile(one_chip):
+    """The Clay repair's device relayouts around the apply, at the Clay
+    cell's size: 11 helpers x 64 stripes x 16 planes of 8 KiB in, the
+    [64, 64 x 8 KiB] output back to stripe-major bytes."""
+    import jax.numpy as jnp
+
+    from ceph_tpu.ec.plugins.clay import _plane_major, _stripe_major
+
+    stripes, nB, sub_len = 64, 16, 8192
+    rows = tuple(_spec((stripes * nB, sub_len), jnp.uint8, one_chip)
+                 for _ in range(11))
+    compiled = _plane_major.lower(rows, stripes).compile()
+    assert compiled.memory_analysis().output_size_in_bytes == \
+        11 * nB * stripes * sub_len
+    compiled = _stripe_major.lower(
+        _spec((64, stripes * sub_len), jnp.uint8, one_chip), stripes).compile()
+    assert compiled.memory_analysis().output_size_in_bytes == \
+        64 * stripes * sub_len
+
+
 def test_straw2_scorer_compiles(one_chip):
     """The straw2 scorer at the mapper's default tile, at the S=128 width
     of the 1024-OSD map's root bucket."""

@@ -121,6 +121,45 @@ def test_decode_stages_and_bytes(tmp_path, shards):
     assert codec._jax_codec.rebuild_matrix_hits == 1
 
 
+def test_clay_repair_stages_and_bytes(tmp_path):
+    """A Clay single-shard repair of three stripes from the helpers'
+    repair-plane sub-chunks: one clay.repair around the matrix build,
+    the gather, one gf.* round trip and the scatter."""
+    from ceph_tpu.ec.registry import ErasureCodePluginRegistry
+
+    codec = ErasureCodePluginRegistry.instance().factory(
+        {"plugin": "clay", "k": "4", "m": "2", "technique": "cauchy_orig"})
+    Z, sub, lost = codec.sub_chunk_count, 32, 3
+    data = np.random.default_rng(5).integers(0, 256, (3, 4, Z * sub),
+                                             dtype=np.uint8)
+    shards = np.concatenate(
+        [data, np.stack([codec.encode_chunks(s) for s in data])], axis=1)
+    planes = codec.repair_planes(lost)
+    helpers = {h: np.ascontiguousarray(
+        shards[:, h].reshape(3, Z, sub)[:, planes]).reshape(-1)
+        for h in range(6) if h != lost}
+    out, spans = _profiled(tmp_path,
+                           lambda: codec.decode({lost}, helpers, Z * sub))
+    assert out[lost].tobytes() == shards[:, lost].tobytes()
+    names = {s[0] for s in spans}
+    assert names == {"clay.repair", "clay.repair_matrix", "clay.gather",
+                     "clay.scatter", "gf.h2d", "gf.dispatch", "gf.wait",
+                     "gf.d2h"}
+    assert names <= set(DEVICE_STAGES)
+    for inner in ("clay.repair_matrix", "clay.gather", "clay.scatter",
+                  "gf."):
+        _assert_nested(spans, inner, {"clay.repair"})
+    count = {n: sum(s[0] == n for s in spans) for n in names}
+    assert count["clay.repair"] == count["gf.dispatch"] == 1
+    assert (codec.repair_matrix_hits, codec.repair_matrix_misses) == (0, 1)
+    assert codec.repair_bytes_in == 5 * 3 * len(planes) * sub
+    assert codec.repair_bytes_out == 3 * Z * sub
+    _, again = _profiled(tmp_path / "again",
+                         lambda: codec.decode({lost}, helpers, Z * sub))
+    assert "clay.repair_matrix" not in {s[0] for s in again}
+    assert codec.repair_matrix_hits == 1
+
+
 def _osdmap():
     from ceph_tpu.crush import CrushWrapper, build_hierarchical_map
     from ceph_tpu.osd import OSDMap
