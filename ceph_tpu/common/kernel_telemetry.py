@@ -259,6 +259,30 @@ class KernelTelemetry:
                             "Clay repair-matrix cache",
                             "ec/plugins/clay.py")
 
+    def record_d2h(self, minor_faults: int) -> None:
+        """One codec device-to-host fetch (ec/plugins/rs.py `_to_host`):
+        `d2h_fetches` counts it, `d2h_minor_faults` adds the minor page
+        faults the whole process took while it ran (the process-wide
+        `ru_minflt` delta, so another thread's faults count too)."""
+        if not self.enabled:
+            return
+        if "d2h_fetches" not in self._declared:
+            with self._lock:
+                if "d2h_fetches" not in self._declared:
+                    self.perf._add(
+                        "d2h_fetches", "u64",
+                        "codec device-to-host fetches (ec/plugins/rs.py "
+                        "_to_host; docs/tracing.md)")
+                    self.perf._add(
+                        "d2h_minor_faults", "u64",
+                        "minor page faults during the codec's "
+                        "device-to-host fetches, process-wide "
+                        "(docs/tracing.md)")
+                    self._declared.add("d2h_fetches")
+        self.perf.inc("d2h_fetches")
+        if minor_faults > 0:
+            self.perf.inc("d2h_minor_faults", int(minor_faults))
+
     def _record_lookup(self, prefix: str, hit: bool, what: str,
                        where: str) -> None:
         if not self.enabled:

@@ -60,6 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...common.host_heap import retain_freed_large_blocks
 from ...common.kernel_telemetry import TELEMETRY
 from ...common.tracer import stage
 from ...gf.matrix import decode_matrix_for, systematic_generator
@@ -83,6 +84,9 @@ def _gmul(c: int, arr: np.ndarray) -> np.ndarray:
 
 class ClayCodec(ErasureCode):
     def __init__(self, profile: dict | None = None):
+        # the repair's host fetch lands in recycled heap pages, not newly
+        # mapped ones (the same policy the RS codec applies)
+        retain_freed_large_blocks()
         #: (lost, helpers) -> (repair matrix, stable digest)
         self._repair_mat_cache: dict[tuple, tuple[np.ndarray, str]] = {}
         self._count_lock = threading.Lock()
@@ -462,9 +466,12 @@ class ClayCodec(ErasureCode):
         stripe's columns.  Several stripes are laid out on the device,
         around the apply: the helpers' stripe-major rows as its
         plane-major input, its output as stripe-major chunk bytes."""
+        from ...common.device_policy import get_device_policy
         from ...ops.bitplane import apply_matrix_jax
 
         sub_len = chunk_size // self.sub_chunk_count
+        # the relayout kernels run in interpret mode off the chip
+        interpret = get_device_policy().backend() != "tpu"
         with stage("clay.repair"):
             helpers = tuple(sorted(have))
             M, m_key = self.repair_matrix_entry(lost, helpers)
@@ -480,11 +487,11 @@ class ClayCodec(ErasureCode):
                         rows = jax.device_put(
                             [np.asarray(have[h], dtype=np.uint8).reshape(
                                 -1, sub_len) for h in helpers])
-                    x = _plane_major(tuple(rows), stripes)
+                    x = _plane_major(tuple(rows), stripes, interpret)
             out = apply_matrix_jax(M, x, mat_key=m_key)
             if stripes > 1:
                 with stage("clay.scatter"):
-                    out = _stripe_major(out, stripes)
+                    out = _stripe_major(out, stripes, interpret)
             chunk = _to_host(out).reshape(stripes * chunk_size)
         with self._count_lock:
             self.repair_bytes_in += sum(
@@ -493,28 +500,64 @@ class ClayCodec(ErasureCode):
         return chunk
 
 
-# The device relayouts are column slices and concatenates: a reshape that
-# splits or merges the minor dimension of a uint8 device array compiles
-# for minutes on v5e (ops/pallas_gf.py).
-@partial(jax.jit, static_argnums=1)
-def _plane_major(rows, stripes: int):
+# The device relayouts are block copies.  XLA lowers slices and
+# concatenates to one device op a (helper, stripe) block, 768 a call at
+# the Clay cell's size, and a reshape that splits or merges the minor
+# dimension of a uint8 device array compiles for minutes on v5e
+# (ops/pallas_gf.py).  So where a block is a whole number of uint8 tiles
+# (8 rows, 128 bytes) each relayout is one Pallas kernel with a grid step
+# a stripe; other shapes keep the slices.
+def _tiles(nrows: int, sub_len: int) -> bool:
+    return nrows % 8 == 0 and sub_len % 128 == 0
+
+
+@partial(jax.jit, static_argnums=(1, 2))
+def _plane_major(rows, stripes: int, interpret: bool = False):
     """Each helper's stripe-major [stripes*nB, sub_len] rows -> the
     apply's [helpers*nB, stripes*sub_len] input: row (helper, plane)
     holds that plane's sub-chunk of every stripe, stripe after stripe."""
-    nB = rows[0].shape[0] // stripes
-    return jnp.concatenate([
-        jnp.concatenate([r[s * nB:(s + 1) * nB] for s in range(stripes)],
-                        axis=1)
-        for r in rows])
+    nB, sub_len = rows[0].shape[0] // stripes, rows[0].shape[1]
+    if not _tiles(nB, sub_len):
+        return jnp.concatenate([
+            jnp.concatenate([r[s * nB:(s + 1) * nB] for s in range(stripes)],
+                            axis=1)
+            for r in rows])
+    from jax.experimental import pallas as pl
+
+    def copy(*refs):
+        for h, r in enumerate(refs[:-1]):
+            refs[-1][h * nB:(h + 1) * nB, :] = r[...]
+
+    return pl.pallas_call(
+        copy, grid=(stripes,),
+        in_specs=[pl.BlockSpec((nB, sub_len), lambda s: (s, 0))
+                  for _ in rows],
+        out_specs=pl.BlockSpec((len(rows) * nB, sub_len),
+                               lambda s: (0, s)),
+        out_shape=jax.ShapeDtypeStruct(
+            (len(rows) * nB, stripes * sub_len), jnp.uint8),
+        interpret=interpret)(*rows)
 
 
-@partial(jax.jit, static_argnums=1)
-def _stripe_major(out, stripes: int):
+@partial(jax.jit, static_argnums=(1, 2))
+def _stripe_major(out, stripes: int, interpret: bool = False):
     """The apply's [Z, stripes*sub_len] output -> [stripes*Z, sub_len]:
     stripe after stripe, each stripe's chunk bytes."""
-    sub_len = out.shape[1] // stripes
-    return jnp.concatenate([out[:, s * sub_len:(s + 1) * sub_len]
-                            for s in range(stripes)])
+    Z, sub_len = out.shape[0], out.shape[1] // stripes
+    if not _tiles(Z, sub_len):
+        return jnp.concatenate([out[:, s * sub_len:(s + 1) * sub_len]
+                                for s in range(stripes)])
+    from jax.experimental import pallas as pl
+
+    def copy(x, o):
+        o[...] = x[...]
+
+    return pl.pallas_call(
+        copy, grid=(stripes,),
+        in_specs=[pl.BlockSpec((Z, sub_len), lambda s: (0, s))],
+        out_specs=pl.BlockSpec((Z, sub_len), lambda s: (s, 0)),
+        out_shape=jax.ShapeDtypeStruct((stripes * Z, sub_len), jnp.uint8),
+        interpret=interpret)(out)
 
 
 class ClayPlugin(ErasureCodePlugin):

@@ -25,8 +25,11 @@ Parity bytes are identical across backends (byte-wise GF semantics).
 """
 from __future__ import annotations
 
+import resource
+
 import numpy as np
 
+from ...common.kernel_telemetry import TELEMETRY
 from ...common.tracer import stage
 from ...gf.matrix import (
     cauchy_good_coding_matrix,
@@ -62,13 +65,24 @@ def build_coding_matrix(technique: str, k: int, m: int) -> np.ndarray:
     )
 
 
+def _minor_faults() -> int:
+    return resource.getrusage(resource.RUSAGE_SELF).ru_minflt
+
+
 def _to_host(result) -> np.ndarray:
     """Host copy of a GF apply's device result: the wait for the kernel,
-    then the fetch, each its own stage (np.asarray alone does both)."""
+    then the fetch, each its own stage (np.asarray alone does both).
+    With telemetry on, the fetch counts into `d2h_fetches` and the
+    process's minor page faults during it into `d2h_minor_faults`."""
     with stage("gf.wait"):
         result.block_until_ready()
     with stage("gf.d2h"):
-        return np.asarray(result)
+        if not TELEMETRY.enabled:
+            return np.asarray(result)
+        faults = _minor_faults()
+        host = np.asarray(result)
+        TELEMETRY.record_d2h(_minor_faults() - faults)
+        return host
 
 
 class RSCodec(ErasureCode):

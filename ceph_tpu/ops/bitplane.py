@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..common.host_heap import retain_freed_large_blocks
 from ..common.kernel_telemetry import SENTINEL, TELEMETRY
 from ..common.tracer import stage
 from ..gf.matrix import decode_matrix_for, matrix_to_bitmatrix, systematic_generator
@@ -462,6 +463,9 @@ class BitplaneCodec:
     """
 
     def __init__(self, coding: np.ndarray):
+        # the results' host fetches (RS encode and rebuild) land in
+        # recycled heap pages, not newly mapped ones
+        retain_freed_large_blocks()
         self.coding = np.ascontiguousarray(coding, dtype=np.uint8)
         self.m, self.k = self.coding.shape
         # stable device-cache key, computed ONCE per codec (cephdma: the

@@ -152,7 +152,8 @@ def test_gf_kernel_compiles(one_chip, matrix):
 def test_clay_relayouts_compile(one_chip):
     """The Clay repair's device relayouts around the apply, at the Clay
     cell's size: 11 helpers x 64 stripes x 16 planes of 8 KiB in, the
-    [64, 64 x 8 KiB] output back to stripe-major bytes."""
+    [64, 64 x 8 KiB] output back to stripe-major bytes, each one
+    kernel."""
     import jax.numpy as jnp
 
     from ceph_tpu.ec.plugins.clay import _plane_major, _stripe_major
@@ -163,10 +164,12 @@ def test_clay_relayouts_compile(one_chip):
     compiled = _plane_major.lower(rows, stripes).compile()
     assert compiled.memory_analysis().output_size_in_bytes == \
         11 * nB * stripes * sub_len
+    assert compiled.as_text().count("tpu_custom_call") == 1
     compiled = _stripe_major.lower(
         _spec((64, stripes * sub_len), jnp.uint8, one_chip), stripes).compile()
     assert compiled.memory_analysis().output_size_in_bytes == \
         64 * stripes * sub_len
+    assert compiled.as_text().count("tpu_custom_call") == 1
 
 
 def test_straw2_scorer_compiles(one_chip):

@@ -172,3 +172,29 @@ def test_recovery_plan_repairs_through_decode_from_sub_chunks(encoded,
     assert (size, n_helpers) == (4096, 11)
     assert bytes_read == 11 * 16 * SUB_LEN
     assert calls == [1]  # one repair, of one stripe
+
+
+@pytest.mark.parametrize("nB,sub_len", [(16, 128), (4, 64)],
+                         ids=["kernel", "slices"])
+def test_relayouts_equal_a_numpy_transpose(nB, sub_len):
+    """The repair's device relayouts, as one Pallas kernel where a block
+    is whole uint8 tiles and as slices elsewhere, against numpy."""
+    import jax.numpy as jnp
+
+    from ceph_tpu.ec.plugins.clay import _plane_major, _stripe_major
+
+    rng = np.random.default_rng(5)
+    stripes, helpers, Z = 3, 11, 4 * nB
+    rows = [rng.integers(0, 256, (stripes * nB, sub_len), dtype=np.uint8)
+            for _ in range(helpers)]
+    want = np.concatenate([
+        r.reshape(stripes, nB, sub_len).transpose(1, 0, 2).reshape(
+            nB, stripes * sub_len) for r in rows])
+    got = _plane_major(tuple(jnp.asarray(r) for r in rows), stripes, True)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+    out = rng.integers(0, 256, (Z, stripes * sub_len), dtype=np.uint8)
+    want = out.reshape(Z, stripes, sub_len).transpose(1, 0, 2).reshape(
+        stripes * Z, sub_len)
+    got = _stripe_major(jnp.asarray(out), stripes, True)
+    np.testing.assert_array_equal(np.asarray(got), want)
