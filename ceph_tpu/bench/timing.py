@@ -54,9 +54,9 @@ def make_chained_encode(coding: np.ndarray, kernel: str = "xla"):
             return _apply_grouped(B, P, x, rows, n, G, tile, False)
 
     else:
-        from ..ops.bitplane import _apply_bitmatrix, bitmatrix_device
+        from ..ops.bitplane import _apply_bitmatrix, _bitmatrix_for
 
-        B = bitmatrix_device(coding.tobytes(), coding.shape)
+        B = _bitmatrix_for(coding)
 
         def prep(chunks: np.ndarray):
             return jnp.asarray(np.ascontiguousarray(chunks, dtype=np.uint8))

@@ -775,7 +775,7 @@ def run_read_traffic(
     (osd/read_batcher.py) — each op is one stripe's survivor stack
     multiplied through the codec's cached decode matrix, i.e. exactly
     the work a degraded GET costs the primary after its chunk gather.
-    ``batched`` coalesces every concurrent op's stack into one pooled
+    ``batched`` coalesces every concurrent op's stack into one
     ``apply_matrix_jax`` dispatch per flush; ``perop`` runs the same
     submits with coalescing off (osd_read_batch_window_ms=0), today's
     one-dispatch-per-read path.  The ratio is the read_smoke gate."""

@@ -327,6 +327,10 @@ class IoCtx:
         return sorted(oids)
 
 
+def _pool_named(m, name: str) -> int | None:
+    return next((pid for pid, p in m.pools.items() if p.name == name), None)
+
+
 class Rados:
     """reference: librados::Rados — cluster handle."""
 
@@ -350,13 +354,26 @@ class Rados:
         return self.mc.command(cmd, timeout=timeout)
 
     def pool_id(self, name: str) -> int:
+        """The pool's id.  A name this client's map lacks may belong to
+        a pool created since: wait for the mon's current map and look
+        again before failing (reference: librados
+        RadosClient::lookup_pool -> wait_for_latest_osdmap)."""
         m = self.mc.osdmap
         if m is None:
             raise ConnectionError("not connected")
-        for pid, p in m.pools.items():
-            if p.name == name:
-                return pid
-        raise KeyError(f"no pool {name!r}")
+        pid = _pool_named(m, name)
+        if pid is None:
+            rv, dump = self.mc.command({"prefix": "osd dump"}, timeout=3.0)
+            if rv == 0 and dump.get("epoch", 0) > m.epoch:
+                try:
+                    m = self.mc.wait_for_osdmap(min_epoch=dump["epoch"],
+                                                timeout=3.0)
+                    pid = _pool_named(m, name)
+                except TimeoutError:
+                    pass  # the newer map never came: as unknown
+        if pid is None:
+            raise KeyError(f"no pool {name!r}")
+        return pid
 
     def open_ioctx(self, pool: str | int) -> IoCtx:
         if isinstance(pool, str):

@@ -8,9 +8,8 @@ scattered through the package: each ambient site hard-codes "whatever
 this process happens to see" as the topology.  So topology becomes a
 value: a ``DevicePolicy`` built ONCE from the daemon's conf
 (``device_topology`` / ``device_mesh_shape``) and constructor-injected
-into the seams that need it — the OSD daemon, the device stripe pool,
-bitplane/pipeline dispatch, ``crush_do_rule_batch``, and
-``parallel.mesh``.  cephlint CL9 (qa/analyzer/cl9_topology.py) enforces
+into the seams that need it — the OSD daemon, bitplane/pipeline
+dispatch, ``crush_do_rule_batch``, and ``parallel.mesh``.  cephlint CL9 (qa/analyzer/cl9_topology.py) enforces
 the discipline: this file is the one allowlisted module where the
 ambient probes may live; everywhere else they are lint errors.
 
@@ -20,15 +19,15 @@ Variants (the ``device_topology`` option):
 - ``mesh``   — multi-chip: every healthy device (``device_mesh_shape``
   caps the axis length; 0 = all).
 - ``cpu``    — CPU fallback: a 1-device mesh on the cpu platform, and
-  ``backend()`` reports ``cpu`` so dispatch (pallas/donation/limb
-  engine) takes the host-safe path even when an accelerator exists.
+  ``backend()`` reports ``cpu`` so dispatch (pallas/limb engine) takes
+  the host-safe path even when an accelerator exists.
 - ``auto``   — ``mesh`` when more than one healthy device is visible,
   else ``single`` (the pre-policy behavior, preserved).
 
 Sentinel-aware: the PR-15 per-device probe rows
 (``ceph_backend_device_*``; kernel_telemetry.BackendSentinel.devices())
 mark individual sick chips, and ``healthy_devices()`` subtracts them —
-a failed probe SHRINKS the mesh and the pool budget instead of wedging
+a failed probe SHRINKS the mesh instead of wedging
 the data plane on a dead chip.  ``failed=`` pins additional devices out
 (tests and the degraded-topology smoke inject deterministic failures
 without running a sentinel cycle).
@@ -88,7 +87,7 @@ class DevicePolicy:
 
     def backend(self) -> str:
         """The backend name dispatch decisions key on (`_want_pallas`,
-        donation, the CRUSH limb/i64 engine pick).  The cpu variant
+        the CRUSH limb/i64 engine pick).  The cpu variant
         pins it to "cpu" — that is the fallback's whole point."""
         import jax
 
@@ -158,21 +157,6 @@ class DevicePolicy:
             devs = devs[:n_devices]
         return mesh_over(devs, axis)
 
-    # -- budgets -----------------------------------------------------------
-    def pool_budget(self, max_bytes: int) -> int:
-        """The device pool's effective residency bound under this
-        policy: the configured max spread evenly over the FULL granted
-        mesh, times the devices still healthy.  A sentinel device
-        failure thus shrinks the pool's footprint with the mesh instead
-        of letting survivors inherit the dead chip's share; a fully
-        healthy mesh gets the whole configured bound."""
-        full = self._grant(self.all_devices())
-        if not full:
-            return int(max_bytes)
-        per_dev = int(max_bytes) // len(full)
-        live = min(len(self.devices()), len(full))
-        return max(per_dev, per_dev * live)
-
 
 def mesh_over(devices, axis: str):
     """Build a 1-axis Mesh over an explicit device list/array.  The
@@ -194,7 +178,7 @@ _conf_applied = False
 
 def configure_device_policy(policy: DevicePolicy) -> DevicePolicy:
     """Install the daemon's policy process-wide.  FIRST daemon in the
-    process wins (kernel dispatch and the pool are process-wide, so a
+    process wins (kernel dispatch is process-wide, so a
     second daemon must not silently re-topologize them); returns the
     policy actually in force so the caller can hold the real one."""
     global _POLICY, _conf_applied

@@ -7,10 +7,10 @@ so degradation must be a first-class, alertable cluster state, not a
 bench footnote).
 
 Two layers, both process-wide (kernel dispatch is per-process, like the
-`ec_kernel` override and the cephtrace TRACER):
+cephtrace TRACER):
 
 - **KernelTelemetry** (``TELEMETRY``): one record per kernel entry point
-  (``gf_apply``, ``gf_xor``, ``stream_encode``, ``ec_batch_flush``,
+  (``gf_apply``, ``stream_encode``, ``ec_batch_flush``,
   ``crush_do_rule_batch``) — invocation counts, compile-vs-execute wall
   time as log2 histograms (the PR-9 ``TYPE_HISTOGRAM``), bytes in/out,
   achieved GiB/s where the call is a true sync point, and the backend
@@ -74,11 +74,10 @@ class _KernelStats:
         self.last_backend: str | None = None
         self.last_ts: float | None = None
         self.last_gibps: float | None = None
-        # cephdma: bytes this kernel's dispatch seam copied through host
-        # memory (staging packs, host->device commits, device->host
+        # bytes this kernel's dispatch seam copied through host memory
+        # (staging packs, host->device commits, device->host
         # materializations) and how many of its calls were sync points
-        # (blocked on a device round trip) — the pair the device-pool
-        # control-vs-pool audit compares (docs/write_path.md)
+        # (blocked on a device round trip; docs/write_path.md)
         self.host_copy_bytes = 0
         self.sync_points = 0
 
@@ -210,36 +209,6 @@ class KernelTelemetry:
             self.perf.inc(f"{kernel}_host_copy_bytes", int(host_copy_bytes))
         if synced:
             self.perf.inc(f"{kernel}_sync_points")
-
-    # -- device-pool mirror (ops/device_pool.py) ---------------------------
-    _POOL_COUNTERS = ("hits", "misses", "evictions", "donations")
-
-    def record_pool(self, hits: int = 0, misses: int = 0,
-                    evictions: int = 0, donations: int = 0,
-                    resident_bytes: int | None = None) -> None:
-        """Mirror device-pool stat deltas into the shared PerfCounters so
-        `device_pool_*` series ride the same perf dump -> MMgrReport ->
-        prometheus pipeline as the kernel records (the pool keeps its own
-        authoritative totals; this is the export seam)."""
-        if not self.enabled:
-            return
-        with self._lock:
-            if "device_pool_hits" not in self._declared:
-                self._declared.add("device_pool_hits")
-                for name in self._POOL_COUNTERS:
-                    self.perf._add(
-                        f"device_pool_{name}", "u64",
-                        f"device stripe pool {name} "
-                        f"(ops/device_pool.py; docs/write_path.md)")
-                self.perf._add(
-                    "device_pool_resident_bytes", "gauge",
-                    "device stripe pool free-list residency in bytes")
-        for name, v in (("hits", hits), ("misses", misses),
-                        ("evictions", evictions), ("donations", donations)):
-            if v:
-                self.perf.inc(f"device_pool_{name}", int(v))
-        if resident_bytes is not None:
-            self.perf.set("device_pool_resident_bytes", int(resident_bytes))
 
     def record_rebuild_matrix(self, hit: bool) -> None:
         """One lookup of an RS codec's rebuild-matrix cache

@@ -469,28 +469,6 @@ def default_options() -> OptionTable:
                    "per-client accounting table (the heavy-hitter rows) "
                    "— a cold scan never churns the cache.  0 promotes "
                    "every full-object read", min=0, runtime=True),
-            Option("ec_device_pool", bool, True,
-                   "cephdma: device-resident stripe-buffer pool + fully "
-                   "async encode path (ops/device_pool.py; "
-                   "docs/write_path.md).  On: batcher flushes pack into "
-                   "pooled device buffers, encode through the donated "
-                   "jit, keep parity device-resident through demux, and "
-                   "sync only at each op's encode_wait commit point.  "
-                   "Off (or whenever the backend sentinel has latched "
-                   "degraded): the historical synchronous flush — pack "
-                   "on host, device round trip, fetch on the flusher.  "
-                   "Read at daemon start into the process-wide pool and "
-                   "re-read per flush by the batcher; an injectargs "
-                   "flip also reconfigures the process-wide pool "
-                   "(OSD-registered observer — disengages the stream/"
-                   "decode/recovery paths too; last write wins, like "
-                   "ec_kernel)", runtime=True),
-            Option("ec_device_pool_max_bytes", int, 256 << 20,
-                   "bound on the device stripe pool's free-list "
-                   "residency; past it least-recently-used buffer "
-                   "geometries evict.  Read once at daemon start into "
-                   "the process-wide pool (first daemon wins, like the "
-                   "sentinel policy) — restart to change", min=0),
             Option("kernel_telemetry", bool, True,
                    "per-kernel dispatch telemetry registry "
                    "(common/kernel_telemetry.py): invocation counts, "
@@ -520,11 +498,11 @@ def default_options() -> OptionTable:
                    "process (common/device_policy.py): single = default "
                    "chip only; mesh = multi-chip mesh over the healthy "
                    "devices; cpu = CPU-fallback 1-device mesh (dispatch "
-                   "treats the backend as cpu — no pallas, no donation, "
+                   "treats the backend as cpu — no pallas, "
                    "no limb engine); auto = mesh when more than one "
                    "healthy device is visible, else single.  Sentinel "
                    "per-device probe failures (ceph_backend_device_*) "
-                   "shrink the granted mesh and the pool budget instead "
+                   "shrink the granted mesh instead "
                    "of wedging.  Read ONCE at daemon start into the "
                    "process-wide injected policy (first daemon wins, "
                    "like the sentinel) — restart to change",
@@ -534,13 +512,5 @@ def default_options() -> OptionTable:
                    "count) the device policy grants; 0 = every healthy "
                    "device.  Read once at daemon start with "
                    "device_topology", min=0),
-            Option("ec_kernel", str, "auto",
-                   "encode kernel selection for the default (jax) EC "
-                   "plugin: oracle/numpy swap the backend, xla/pallas "
-                   "force the GF kernel path (process-wide, mirrors "
-                   "CEPH_TPU_EC_KERNEL); auto keeps TPU dispatch. "
-                   "Applied when a pool's codec is first compiled — set "
-                   "it at daemon construction, not injectargs",
-                   enum=("auto", "xla", "pallas", "oracle", "numpy")),
         ]
     )

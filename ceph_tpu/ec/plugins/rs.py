@@ -160,21 +160,7 @@ class RSCodec(ErasureCode):
         one fetch of [len(missing), L]."""
         if not missing:
             return {}
-        # cephdma: the gathered helper stack commits to the device
-        # through the stripe pool (recovery's _rebuild_shard_chunk and
-        # degraded reads both land here), so repeated rebuilds of one
-        # geometry recycle buffers instead of allocating
-        from ...ops.device_pool import POOL
-
-        with stage("gf.h2d"):
-            dev = POOL.put(shards) if POOL.enabled() else shards
-        try:
-            rebuilt = _to_host(self._jax_codec.reconstruct(use, dev, missing))
-        finally:
-            # a decode failure (bad shard set, kernel abort) must not
-            # strand the pooled stripe buffer
-            if dev is not shards:
-                POOL.release(dev)
+        rebuilt = _to_host(self._jax_codec.reconstruct(use, shards, missing))
         return dict(zip(missing, rebuilt))
 
     def _rebuild_on_host(self, use, shards, missing) -> dict:
@@ -234,7 +220,7 @@ class BitmatrixCodec(ErasureCode):
             raise InvalidProfile(str(e))
         self._gf2_inv = gf2_inv
         if self.backend == "jax":
-            # stable device-cache key, once per codec (cephdma)
+            # stable device-cache key, once per codec
             from ...ops.bitplane import matrix_digest
 
             self._B_digest = matrix_digest(self.B)
@@ -246,33 +232,15 @@ class BitmatrixCodec(ErasureCode):
     def _apply(self, M: np.ndarray, rows: np.ndarray,
                mat_key: str | None = None) -> np.ndarray:
         if self.backend == "jax":
-            # cephdma: the packet rows commit through the device stripe
-            # pool and the XOR apply runs the donation-enabled variant
-            # (apply_xor_matrix_dev) — the bitmatrix codecs encode
-            # inline (not batcher-eligible), so this is their whole
-            # pool/donation story; the np.asarray is their deliberate
-            # codec-seam sync
-            from ...ops.bitplane import (
-                apply_xor_matrix_dev,
-                apply_xor_matrix_jax,
-            )
-            from ...ops.device_pool import POOL, donation_supported
+            # the packet codes encode inline (not batcher-eligible): the
+            # np.asarray is their deliberate codec-seam sync
+            import jax.numpy as jnp
 
-            if POOL.enabled():
-                don = donation_supported()
-                dev = POOL.put(rows)
-                try:
-                    out = np.asarray(apply_xor_matrix_dev(
-                        M, dev, mat_key=mat_key, donate=don))
-                finally:
-                    if not don:
-                        # donated buffers are consumed by the kernel;
-                        # an undonated one is dead now (or the apply
-                        # raised) and recycles either way
-                        POOL.release(dev)
-                return out
-            return np.asarray(apply_xor_matrix_jax(M, rows,
-                                                   mat_key=mat_key))
+            from ...ops.bitplane import apply_matrix_jax
+
+            with stage("gf.h2d"):
+                dev = jnp.asarray(rows, dtype=jnp.uint8)
+            return np.asarray(apply_matrix_jax(M, dev, mat_key=mat_key))
         out = np.zeros((M.shape[0], rows.shape[1]), dtype=np.uint8)
         for r in range(M.shape[0]):
             for j in np.nonzero(M[r])[0]:

@@ -18,6 +18,15 @@ ECBackend's shard layout, reference: src/osd/ECUtil.h :: stripe_info_t), so
 one matmul covers every stripe of an object, and shard_len is the batch axis
 sharded across chips by ceph_tpu.parallel.
 
+The seam every codec shares is here, in two steps: a host stripe
+reaches the device by one `jnp.asarray(x, dtype=jnp.uint8)` under the
+`gf.h2d` stage, and `apply_matrix_jax` applies the GF(2^8) matrix to it
+(the fused Pallas kernel on TPU, `_apply_bitmatrix` elsewhere), leaving
+the result on the device for the caller to fetch.  The RS codecs, both
+batchers, the EC backend's ranged decode and the stream pipeline commit
+that way; the SHEC and Clay plugins hand `apply_matrix_jax` host arrays,
+so their transfer is made, and timed, inside `gf.dispatch`.
+
 Bit-exactness: all ops are exact integer ops; tests assert parity bytes are
 identical to the C++ oracle (native/gf_oracle.cc).
 """
@@ -27,7 +36,6 @@ import hashlib
 import os
 import time
 from collections import OrderedDict
-from functools import lru_cache, partial
 from threading import Lock
 
 import jax
@@ -57,10 +65,10 @@ def pack_bitplanes(bits: jnp.ndarray) -> jnp.ndarray:
     return (b << jnp.asarray(_BIT_IDX)[None, :, None]).sum(axis=1, dtype=jnp.uint8)
 
 
-def _bitmatrix_body(B: jnp.ndarray, chunks: jnp.ndarray) -> jnp.ndarray:
-    """(rows*8 x n*8) GF(2) matrix times [n, L] byte chunks -> [rows, L]
-    — THE encode math, written once and wrapped below (plain, donated,
-    and fused variants must never diverge byte-wise)."""
+@jax.jit
+def _apply_bitmatrix(B: jnp.ndarray, chunks: jnp.ndarray) -> jnp.ndarray:
+    """(rows*8 x n*8) GF(2) matrix times [n, L] byte chunks -> [rows, L]:
+    the XLA bitplane apply (bitplanes round-trip through HBM)."""
     bits = unpack_bitplanes(chunks)
     acc = jax.lax.dot_general(
         B,
@@ -71,55 +79,38 @@ def _bitmatrix_body(B: jnp.ndarray, chunks: jnp.ndarray) -> jnp.ndarray:
     return pack_bitplanes((acc & 1).astype(jnp.uint8))
 
 
-_apply_bitmatrix = jax.jit(_bitmatrix_body)
-
-#: _apply_bitmatrix with the packed stripe buffer DONATED (SNIPPETS.md
-#: [1]/[3] `donation_vector` machinery behind `donate_argnums`): a
-#: flush's input buffer is recycled for the kernel's bitplane workspace/
-#: output instead of allocating fresh — real on donating backends
-#: (TPU/GPU), a no-op annotation on CPU.  The caller must own `chunks`
-#: exclusively (the write batcher's pooled pack does; never donate a
-#: caller-visible array).
-_apply_bitmatrix_donated = jax.jit(_bitmatrix_body, donate_argnums=(1,))
-
-
 def matrix_digest(mat: np.ndarray) -> str:
-    """Stable identity of a coding matrix (shape + bytes) — computed
-    ONCE per codec/cached-decode-matrix and used as the device-cache key
-    so the hot path stops paying a fresh `mat.tobytes()` host copy per
-    stripe (the cephdma satellite fix)."""
+    """Stable identity of a coding matrix (shape + bytes), computed once
+    per codec or cached decode matrix and used as the device-cache key,
+    so the hot path pays no `mat.tobytes()` host copy per call."""
     mat = np.ascontiguousarray(mat, dtype=np.uint8)
     h = hashlib.sha1(repr(mat.shape).encode())
     h.update(mat.tobytes())
     return h.hexdigest()
 
 
-#: digest-keyed device bitmatrix cache (LRU, same bound as the legacy
-#: tobytes-keyed lru_cache); one lock — lookups are dict reads
-_BITMATRIX_BY_KEY: OrderedDict[tuple, jnp.ndarray] = OrderedDict()
+#: digest-keyed device bitmatrix cache (LRU, the analog of
+#: ErasureCodeIsaTableCache's per-pattern table cache, reference:
+#: src/erasure-code/isa/ErasureCodeIsaTableCache.cc); lookups are dict
+#: reads under one lock
+_BITMATRIX_BY_KEY: OrderedDict[str, jnp.ndarray] = OrderedDict()
 _BITMATRIX_LOCK = Lock()
 _BITMATRIX_MAX = 256
 
 
-def _bitmatrix_for(mat: np.ndarray, mat_key: str | None,
-                   xor: bool = False) -> jnp.ndarray:
-    """Device bitmatrix for `mat`: by precomputed stable digest when the
-    caller holds one (codec hot path — no per-call host copy), else the
-    legacy tobytes-keyed cache."""
-    if mat_key is None:
-        m = np.ascontiguousarray(mat, dtype=np.uint8)
-        if xor:
-            return xor_bitmatrix_device(m.tobytes(), m.shape)
-        return bitmatrix_device(m.tobytes(), m.shape)
-    key = (mat_key, bool(xor))
+def _bitmatrix_for(mat: np.ndarray, mat_key: str | None = None) -> jnp.ndarray:
+    """Device bitmatrix of `mat`, cached by `mat_key` (the caller's
+    precomputed matrix_digest; computed here when it has none).  A 0/1
+    matrix needs no path of its own: its bitmatrix is kron(B, I_8), so
+    the XOR codes' packet matrices share this cache and the apply."""
+    key = mat_key if mat_key is not None else matrix_digest(mat)
     with _BITMATRIX_LOCK:
         B = _BITMATRIX_BY_KEY.get(key)
         if B is not None:
             _BITMATRIX_BY_KEY.move_to_end(key)
             return B
-    m = np.ascontiguousarray(mat, dtype=np.uint8)
-    B = (jnp.asarray(np.kron(m, np.eye(8, dtype=np.int8))) if xor
-         else jnp.asarray(matrix_to_bitmatrix(m), dtype=jnp.int8))
+    B = jnp.asarray(matrix_to_bitmatrix(np.ascontiguousarray(mat, np.uint8)),
+                    dtype=jnp.int8)
     with _BITMATRIX_LOCK:
         _BITMATRIX_BY_KEY[key] = B
         _BITMATRIX_BY_KEY.move_to_end(key)
@@ -128,44 +119,16 @@ def _bitmatrix_for(mat: np.ndarray, mat_key: str | None,
     return B
 
 
-def apply_matrix_xla(mat: np.ndarray, chunks,
-                     mat_key: str | None = None) -> jnp.ndarray:
-    """GF(2^8) matrix (rows x n, uint8 elements) applied to byte chunks via
-    the XLA bitplane matmul (bitplanes round-trip through HBM).
-
-    Byte-wise GF semantics identical to the oracle's gfo_apply (ISA-L
-    convention) for every technique.  `mat_key`: the codec's precomputed
-    stable digest of `mat` — skips the per-call tobytes host copy.
-    """
-    B = _bitmatrix_for(mat, mat_key)
-    chunks = jnp.asarray(chunks, dtype=jnp.uint8)
-    return _apply_bitmatrix(B, chunks)
-
-
-# Config-surface override (the `ec_kernel` option): process-wide like the
-# env knob it mirrors — kernel dispatch is per-process, not per-daemon,
-# so the last daemon to boot with an explicit setting wins.
-_kernel_override: str | None = None
-
-
-def set_kernel_override(mode: str | None) -> None:
-    """Force the GF kernel path from config ('xla'/'pallas'; None/'auto'
-    clears).  Takes precedence over CEPH_TPU_EC_KERNEL."""
-    global _kernel_override
-    _kernel_override = None if mode in (None, "auto") else mode
-
-
 def _want_pallas() -> bool:
-    """Kernel dispatch policy (round-4 verdict item #3: the production
-    registry -> codec path must reach the fused Pallas kernel on TPU).
+    """Kernel dispatch policy: the production registry -> codec path
+    reaches the fused Pallas kernel on TPU.
 
-    CEPH_TPU_EC_KERNEL: "pallas" / "xla" force a path; default "auto"
-    picks the fused kernel on the TPU backend and the XLA gather-free
-    bitplane path elsewhere.
-    The `ec_kernel` config option sets the same switch programmatically
-    (set_kernel_override) and wins over the env var.
+    CEPH_TPU_EC_KERNEL: "pallas" / "xla" force a path (the benchmark's
+    rehearsal and chip_smoke.py force the chip's kernel on the CPU,
+    where Pallas runs in interpret mode); the default "auto" picks the
+    fused kernel on the TPU backend and the XLA bitplane path elsewhere.
     """
-    mode = _kernel_override or os.environ.get("CEPH_TPU_EC_KERNEL", "auto")
+    mode = os.environ.get("CEPH_TPU_EC_KERNEL", "auto")
     if mode == "pallas":
         return True
     if mode == "xla":
@@ -186,272 +149,61 @@ def _want_pallas() -> bool:
 
 
 def current_backend() -> str:
-    """The GF kernel auto dispatch would pick right now ('pallas'/'xla')
-    — telemetry provenance for call sites above this seam."""
+    """The GF kernel dispatch picks right now ('pallas'/'xla') —
+    telemetry provenance for call sites above this seam."""
     return "pallas" if _want_pallas() else "xla"
-
-
-def _apply_matrix_dispatch(mat: np.ndarray, chunks,
-                           mat_key: str | None = None,
-                           donate: bool = False) -> tuple:
-    """(result, backend) — the dispatch body of apply_matrix_jax, split
-    out so the telemetry wrapper can attribute the call to the backend
-    that served it.  A Pallas failure raises: the kernel's layouts are
-    compile-checked for the chip (tests/test_chip_compile.py), so a
-    failure here is a fault to see, not one to hide behind XLA.
-    `donate=True` routes the XLA path through the donation-enabled jit
-    (caller owns `chunks` exclusively — the pooled pack contract); the
-    Pallas route ignores it (its VMEM kernel manages its own buffers).
-    The whole body is the `gf.dispatch` stage."""
-    with stage("gf.dispatch"):
-        if _want_pallas():
-            from .pallas_gf import apply_matrix_pallas
-
-            from ..common.device_policy import get_device_policy
-
-            # interpret mode only off the chip (CEPH_TPU_EC_KERNEL=pallas
-            # on a CPU host); "tpu" is the one backend name that compiles
-            return apply_matrix_pallas(
-                mat, chunks,
-                interpret=get_device_policy().backend() != "tpu",
-            ), "pallas"
-        if donate:
-            # only take the donated jit where the backend honors donation
-            # (CPU accepts-and-ignores it, with a warning per shape): the
-            # non-donating path is byte-identical, so nothing is lost
-            from .device_pool import donation_supported
-
-            if donation_supported():
-                B = _bitmatrix_for(mat, mat_key)
-                return _apply_bitmatrix_donated(
-                    B, jnp.asarray(chunks, dtype=jnp.uint8)), "xla"
-        return apply_matrix_xla(mat, chunks, mat_key=mat_key), "xla"
-
-
-def apply_matrix_dev(mat: np.ndarray, chunks, mat_key: str | None = None,
-                     donate: bool = False) -> jnp.ndarray:
-    """Device-resident GF(2^8) matrix apply: same kernel dispatch as
-    apply_matrix_jax, but the result STAYS a device array and the call
-    never blocks — the cephdma async encode seam.  The caller owns the
-    single deliberate sync (its commit-point `np.asarray`) and accounts
-    it there; this records an async (synced=False) telemetry sample with
-    zero host-copy bytes.  `donate=True` recycles `chunks`' device
-    buffer into the kernel (the packed-stripe-buffer donation — `chunks`
-    must be an exclusively-owned device array; a donated buffer is dead
-    to the caller afterward)."""
-    tm = TELEMETRY
-    if not tm.enabled:
-        return _apply_matrix_dispatch(mat, chunks, mat_key, donate)[0]
-    t0 = time.perf_counter()
-    out, backend = _apply_matrix_dispatch(mat, chunks, mat_key, donate)
-    dt = time.perf_counter() - t0
-    shape = getattr(chunks, "shape", None)
-    tm.record(
-        "gf_apply", backend, dt,
-        bytes_in=int(getattr(chunks, "nbytes", 0)),
-        bytes_out=mat.shape[0] * shape[-1] if shape else 0,
-        compiled=tm.first_call(("gf_apply", mat.shape, shape, backend,
-                                donate)),
-    )
-    return out
-
-
-@lru_cache(maxsize=256)
-def _fused_encode_jit(nargs: int, donate: bool):
-    """One jitted program per stripe count: commit of the host stripe
-    args, the column concat, AND the bitplane encode fuse into a single
-    dispatch — the pack never exists as a host staging copy and XLA
-    sees the whole flush (donate=True additionally donates every stripe
-    arg's committed buffer into the kernel)."""
-
-    def body(B, *chunks):
-        x = chunks[0] if len(chunks) == 1 else \
-            jnp.concatenate(chunks, axis=1)
-        return _bitmatrix_body(B, x)
-
-    return jax.jit(body, donate_argnums=tuple(range(1, nargs + 1))
-                   if donate else ())
-
-
-def fused_bucket(n: int) -> int:
-    """The arity fused_encode_async actually dispatches for `n` stripes
-    (next power of two; pads are zero stripes) — exposed so the flush
-    seam's host-copy accounting can charge the REAL transfer volume,
-    pads included."""
-    return 1 << max(0, n - 1).bit_length() if n > 1 else 1
-
-
-def fused_encode_async(mat: np.ndarray, chunks_list,
-                       mat_key: str | None = None,
-                       donate: bool = False) -> tuple[jnp.ndarray, str]:
-    """Fused multi-stripe encode, fully async: [k, L] stripes (host or
-    device) -> (ONE device-resident [m, sum(L)] parity array, the
-    backend that served it), in ONE dispatch, bit-identical to
-    apply_matrix_jax on the host-concatenated pack.  The cephdma flush
-    seam: no host staging pack, no fetch — the caller owns the single
-    commit-point materialization and its accounting, and records the
-    returned backend.  `donate=True` donates the stripes' committed
-    buffers into the kernel on backends that honor donation."""
-    n = len(chunks_list)
-    if _want_pallas():
-        # the Pallas VMEM kernel keeps its own packing; hand it the
-        # host pack and stay async through apply_matrix_dev
-        packed = chunks_list[0] if n == 1 else \
-            np.concatenate([np.asarray(c) for c in chunks_list], axis=1)
-        return apply_matrix_dev(mat, packed, mat_key=mat_key,
-                                donate=donate), "pallas"
-    if donate:
-        from .device_pool import donation_supported
-
-        donate = donation_supported()
-    # bucket the arity to the next power of two with zero stripes (the
-    # extra parity columns are zeros past every caller's demux window):
-    # a traffic run's stripe counts drift over 1..max_stripes, and an
-    # unbucketed jit compiles per DISTINCT count — measured as 200 ms+
-    # p99 stalls whenever a novel count appeared mid-run.  7 variants
-    # warm quickly; the pad waste is bounded at <2x and the pads are
-    # fresh zeros (donation-safe: every donated arg a distinct buffer).
-    bucket = fused_bucket(n)
-    if bucket > n:
-        shape = chunks_list[0].shape
-        chunks_list = list(chunks_list) + [
-            np.zeros(shape, dtype=np.uint8) for _ in range(bucket - n)]
-    B = _bitmatrix_for(mat, mat_key)
-    fn = _fused_encode_jit(bucket, donate)
-    tm = TELEMETRY
-    if not tm.enabled:
-        return fn(B, *chunks_list), "xla"
-    t0 = time.perf_counter()
-    out = fn(B, *chunks_list)
-    dt = time.perf_counter() - t0
-    # bytes_in counts what was actually committed, pads included
-    tm.record(
-        "gf_apply", "xla", dt,
-        bytes_in=sum(int(getattr(c, "nbytes", 0)) for c in chunks_list),
-        bytes_out=mat.shape[0] * n * chunks_list[0].shape[1],
-        compiled=tm.first_call(
-            ("gf_fused", mat.shape, bucket, chunks_list[0].shape,
-             donate)),
-    )
-    return out, "xla"
 
 
 def apply_matrix_jax(mat: np.ndarray, chunks,
                      mat_key: str | None = None) -> jnp.ndarray:
-    """GF(2^8) matrix apply with kernel dispatch: the fused Pallas VMEM
-    kernel on TPU (ops/pallas_gf.py), the XLA bitplane path elsewhere.
+    """The GF(2^8) matrix apply: [rows, n] x [n, L] byte chunks (host or
+    device) -> [rows, L] device array.  Never blocks: the caller owns
+    the fetch.
 
-    This is the single entry every production codec (rs/shec/clay plugin
-    encode/decode/repair) goes through, so `plugin=jax` via the registry
-    runs the same kernel the headline bench measures.  A Pallas failure
-    raises; nothing falls back to XLA behind the caller's back.
-    Call sites that record their own telemetry use apply_matrix_served,
-    which also returns the backend that ran.
+    Every codec (rs/shec/clay/lrc plugins, the XOR packet codes, the
+    batchers, the stream pipeline) goes through here, so the registry
+    path runs the kernel the benchmark measures: the fused Pallas VMEM
+    kernel on TPU (ops/pallas_gf.py), the XLA bitplane apply elsewhere.
+    Byte-wise GF semantics identical to the oracle's gfo_apply (ISA-L
+    convention) for every technique.  A Pallas failure raises: the
+    kernel's layouts are compile-checked for the chip
+    (tests/test_chip_compile.py), so nothing falls back to XLA behind
+    the caller's back.  `mat_key`: the caller's precomputed
+    matrix_digest of `mat` (skips hashing it per call).
 
     Telemetry (docs/observability.md): one `gf_apply` record per call —
-    backend, wall time (dispatch-side; JAX queues the launch, so only
+    backend, dispatch-side wall time (JAX queues the launch, so only
     sync call sites above this seam report achieved GiB/s), bytes
     in/out, compile-vs-execute split by first-seen shape.  Disabled:
-    one attribute check.  `mat_key`: precomputed stable digest of `mat`
-    (matrix_digest) held on the codec — skips the per-call tobytes host
-    copy when resolving the cached device bitmatrix.
+    one attribute check.  The whole dispatch is the `gf.dispatch`
+    stage.
     """
-    return apply_matrix_served(mat, chunks, mat_key)[0]
-
-
-def apply_matrix_served(mat: np.ndarray, chunks,
-                        mat_key: str | None = None) -> tuple:
-    """(apply_matrix_jax's result, the backend that served it)."""
     tm = TELEMETRY
-    if not tm.enabled:
-        return _apply_matrix_dispatch(mat, chunks, mat_key)
-    t0 = time.perf_counter()
-    out, backend = _apply_matrix_dispatch(mat, chunks, mat_key)
-    dt = time.perf_counter() - t0
-    shape = getattr(chunks, "shape", None)
-    tm.record(
-        "gf_apply", backend, dt,
-        bytes_in=int(getattr(chunks, "nbytes", 0)),
-        bytes_out=mat.shape[0] * shape[-1] if shape else 0,
-        compiled=tm.first_call(("gf_apply", mat.shape, shape, backend)),
-    )
-    return out, backend
+    t0 = time.perf_counter() if tm.enabled else 0.0
+    with stage("gf.dispatch"):
+        if _want_pallas():
+            from ..common.device_policy import get_device_policy
+            from .pallas_gf import apply_matrix_pallas
 
-
-@lru_cache(maxsize=256)
-def xor_bitmatrix_device(b_bytes: bytes, shape: tuple[int, int]) -> jnp.ndarray:
-    """0/1 XOR-combination matrix expanded to bitplane form: each byte
-    row mixes independently per bit, so the bit-level operator is
-    kron(B, I_8) and the GF(2^8) bitplane kernel serves XOR codes
-    (liberation/blaum_roth/liber8tion packets) unchanged."""
-    B = np.frombuffer(b_bytes, dtype=np.uint8).reshape(shape)
-    return jnp.asarray(np.kron(B, np.eye(8, dtype=np.int8)))
-
-
-def apply_xor_matrix_jax(B: np.ndarray, rows,
-                         mat_key: str | None = None) -> jnp.ndarray:
-    """[R, N] 0/1 matrix XOR-combining [N, L] byte rows -> [R, L], on
-    device through the same MXU bitplane matmul as the GF(2^8) path.
-
-    On TPU this dispatches through apply_matrix_jax: a 0/1 matrix IS a
-    GF(2^8) matrix (multiply-by-1 expands to the identity bitmatrix), so
-    the fused Pallas kernel serves the XOR codes unchanged."""
-    if _want_pallas():
-        return apply_matrix_jax(np.ascontiguousarray(B, dtype=np.uint8),
-                                rows, mat_key=mat_key)
-    Bd = _bitmatrix_for(B, mat_key, xor=True)
-    tm = TELEMETRY
-    if not tm.enabled:
-        return _apply_bitmatrix(Bd, jnp.asarray(rows, dtype=jnp.uint8))
-    t0 = time.perf_counter()
-    out = _apply_bitmatrix(Bd, jnp.asarray(rows, dtype=jnp.uint8))
-    shape = getattr(rows, "shape", None)
-    tm.record(
-        "gf_xor", "xla", time.perf_counter() - t0,
-        bytes_in=int(getattr(rows, "nbytes", 0)),
-        bytes_out=B.shape[0] * shape[-1] if shape else 0,
-        compiled=tm.first_call(("gf_xor", B.shape, shape)),
-    )
+            # interpret mode only off the chip (CEPH_TPU_EC_KERNEL=pallas
+            # on a CPU host); "tpu" is the one backend name that compiles
+            backend = "pallas"
+            out = apply_matrix_pallas(
+                mat, chunks,
+                interpret=get_device_policy().backend() != "tpu")
+        else:
+            backend = "xla"
+            out = _apply_bitmatrix(_bitmatrix_for(mat, mat_key),
+                                   jnp.asarray(chunks, dtype=jnp.uint8))
+    if tm.enabled:
+        shape = getattr(chunks, "shape", None)
+        tm.record(
+            "gf_apply", backend, time.perf_counter() - t0,
+            bytes_in=int(getattr(chunks, "nbytes", 0)),
+            bytes_out=mat.shape[0] * shape[-1] if shape else 0,
+            compiled=tm.first_call(("gf_apply", mat.shape, shape, backend)),
+        )
     return out
-
-
-def apply_xor_matrix_dev(B: np.ndarray, rows, mat_key: str | None = None,
-                         donate: bool = False) -> jnp.ndarray:
-    """Device-resident variant of apply_xor_matrix_jax (the bitmatrix/
-    packet-codec route of the cephdma async seam): result stays on
-    device, no sync; `donate=True` recycles `rows`' exclusively-owned
-    device buffer through the donation-enabled jit."""
-    if _want_pallas():
-        return apply_matrix_dev(np.ascontiguousarray(B, dtype=np.uint8),
-                                rows, mat_key=mat_key, donate=donate)
-    if donate:
-        from .device_pool import donation_supported
-
-        donate = donation_supported()
-    Bd = _bitmatrix_for(B, mat_key, xor=True)
-    fn = _apply_bitmatrix_donated if donate else _apply_bitmatrix
-    tm = TELEMETRY
-    if not tm.enabled:
-        return fn(Bd, jnp.asarray(rows, dtype=jnp.uint8))
-    t0 = time.perf_counter()
-    out = fn(Bd, jnp.asarray(rows, dtype=jnp.uint8))
-    shape = getattr(rows, "shape", None)
-    tm.record(
-        "gf_xor", "xla", time.perf_counter() - t0,
-        bytes_in=int(getattr(rows, "nbytes", 0)),
-        bytes_out=B.shape[0] * shape[-1] if shape else 0,
-        compiled=tm.first_call(("gf_xor", B.shape, shape, donate)),
-    )
-    return out
-
-
-@lru_cache(maxsize=256)
-def bitmatrix_device(mat_bytes: bytes, shape: tuple[int, int]) -> jnp.ndarray:
-    """Host-expanded bitmatrix, cached per coding matrix (the analog of
-    ErasureCodeIsaTableCache's per-pattern table cache, reference:
-    src/erasure-code/isa/ErasureCodeIsaTableCache.cc)."""
-    mat = np.frombuffer(mat_bytes, dtype=np.uint8).reshape(shape)
-    return jnp.asarray(matrix_to_bitmatrix(mat), dtype=jnp.int8)
 
 
 class BitplaneCodec:

@@ -3,12 +3,13 @@
 stages; the TPU equivalent is double-buffering host->device DMA against
 kernel compute).
 
-`stream_encode` drives a sequence of host batches through the encode
-kernel with at most two batches resident: while the device computes
-parity for batch i, batch i+1's transfer is already in flight (both
-device_put and kernel launches are async under JAX's dispatch model;
-the np.asarray fetch of result i-1 is the only sync point and it
-overlaps the later batches' work).
+`stream_encode` drives a sequence of host batches through the production
+GF apply (`ops.bitplane.apply_matrix_jax`) with at most two batches
+resident: while the device computes parity for batch i, batch i+1's
+transfer is already in flight (the `jnp.asarray` commit and the kernel
+launch are both async under JAX's dispatch model; the np.asarray fetch
+of result i-1 is the only sync point and it overlaps the later batches'
+work).
 
 The input is consumed as a true ITERATOR: a long traffic run (the
 write-batcher's multi-batch bursts, bench soaks) holds at most two
@@ -21,66 +22,34 @@ import time
 import numpy as np
 
 from ..common.kernel_telemetry import TELEMETRY
+from ..common.tracer import stage
 
 
-def _apply_fn(mat: np.ndarray, kernel: str, mat_key: str | None,
-              donate: bool):
-    """Resolve the kernel choice once per stream.  'xla' and 'pallas'
-    force a path (the bench's explicit columns); 'auto' routes through
-    the production dispatch — the same path the codec plugins take,
-    honoring the `ec_kernel` option — so
-    batched parity is bit-identical to the per-op path.  With the
-    device pool on, the auto/xla route goes through apply_matrix_dev
-    with the stream's batch buffer DONATED (the stream owns it
-    exclusively) and the stable mat_key skips per-batch tobytes keys."""
-    if kernel == "pallas":
-        from .pallas_gf import apply_matrix_pallas
-
-        return lambda x: apply_matrix_pallas(mat, x)
-    from .bitplane import apply_matrix_dev
-
-    return lambda x: apply_matrix_dev(mat, x, mat_key=mat_key,
-                                      donate=donate)
-
-
-def stream_encode(mat: np.ndarray, batches, kernel: str = "xla",
-                  mat_key: str | None = None):
+def stream_encode(mat: np.ndarray, batches, mat_key: str | None = None):
     """Encode an iterable of [k, L] host batches; returns the list of
-    parity arrays.  kernel: 'xla' (ops.bitplane), 'pallas'
-    (ops.pallas_gf), or 'auto' (production dispatch, ec_kernel-aware).
+    host parity arrays, bit-identical to the per-op path.
 
     `batches` may be any iterable, including a one-shot generator; it is
     pulled lazily, one batch ahead of the compute, so the stream's
     host-memory high-water mark is two batches regardless of length.
-
-    cephdma: batch transfers commit through the device stripe pool
-    (recycled buffers where the backend donates; the pool's bypass —
-    `ec_device_pool=false` or sentinel-degraded — falls back to plain
-    device_put) and the in-flight batch buffer is donated into the
-    encode.  The result fetches stay: returning host parity arrays IS
-    this function's contract, so the stream remains a deliberate sync
-    seam and its record counts the transfer+fetch host-copy volume.
+    `mat_key`: the caller's precomputed matrix_digest of `mat`.
 
     Telemetry: one `stream_encode` record per stream — the np.asarray
     fetches make this a true sync point, so the record carries an honest
-    achieved GiB/s for the whole double-buffered pipeline."""
-    import jax
+    achieved GiB/s for the whole double-buffered pipeline and counts the
+    transfer+fetch host-copy volume."""
+    import jax.numpy as jnp
 
-    from .device_pool import POOL
+    from .bitplane import apply_matrix_jax, current_backend
 
     tm = TELEMETRY
     t_start = time.perf_counter() if tm.enabled else 0.0
-    bytes_in = bytes_out = 0
+    bytes_in = 0
     mat = np.ascontiguousarray(mat, dtype=np.uint8)
-    use_pool = POOL.enabled()
-    apply_fn = _apply_fn(mat, kernel, mat_key,
-                         donate=use_pool and kernel != "pallas")
 
     def commit(host):
-        host = np.ascontiguousarray(host, dtype=np.uint8)
-        if use_pool:
-            return POOL.put(host)
-        return jax.device_put(host)  # noqa: CL8 — pool-off transfer seam
+        with stage("gf.h2d"):
+            return jnp.asarray(host, dtype=jnp.uint8)
 
     it = iter(batches)
     first = next(it, None)
@@ -94,24 +63,17 @@ def stream_encode(mat: np.ndarray, batches, kernel: str = "xla",
         bytes_in += int(cur.nbytes)
         # launch compute first (async), THEN start the next DMA so the
         # copy engine and the cores overlap
-        res = apply_fn(cur)
+        res = apply_matrix_jax(mat, cur, mat_key=mat_key)
         upcoming = next(it, None)
         nxt = commit(upcoming) if upcoming is not None else None
         if pending is not None:
             # fetch the previous result; keeps two batches live
             outs.append(np.asarray(pending))
-            if use_pool:
-                POOL.release(pending)  # dead device buffer: recycle
         pending = res
     outs.append(np.asarray(pending))
-    if use_pool:
-        POOL.release(pending)
     if tm.enabled:
-        from .bitplane import current_backend
-
         bytes_out = sum(int(o.nbytes) for o in outs)
-        backend = kernel if kernel == "pallas" else current_backend()
-        tm.record("stream_encode", backend,
+        tm.record("stream_encode", current_backend(),
                   time.perf_counter() - t_start,
                   bytes_in=bytes_in, bytes_out=bytes_out, synced=True,
                   host_copy_bytes=bytes_in + bytes_out)

@@ -243,7 +243,6 @@ class OSD(
         self._recovery_inflight = False
         self._split_inflight = False
         self._sentinel_held = False  # flipped under self._lock
-        self._pool_observer = None  # conf observer, deregistered at stop
         self.device_policy = None  # injected at start() (cephtopo)
         self._clone_mutex = make_lock("osd::snap_clone")
         # watch/notify state (reference: PrimaryLogPG watchers): primary-
@@ -482,33 +481,16 @@ class OSD(
                     f"{self.whoami}: boot not acknowledged in 30s"
                 )
         self._load_pgs()
-        # cephdma: device stripe pool sized/armed from THIS daemon's
-        # conf (process-wide like the sentinel — first daemon at boot
-        # wins the bound; the batcher re-reads ec_device_pool per flush
-        # so the hatch stays runtime there, and an EXPLICIT injectargs
-        # flips the process-wide pool too via the observer — that's
-        # what lets the hatch disengage the stream/decode/recovery
-        # paths, which consult only POOL.enabled())
-        from ..ops.device_pool import POOL, configure_from_conf
-
         # cephtopo: device-topology policy from THIS daemon's conf
         # (device_topology / device_mesh_shape, read ONCE here),
         # constructor-injected process-wide — first daemon wins, like
-        # the sentinel.  The mesh/pool/dispatch/CRUSH seams consult the
+        # the sentinel.  The mesh/dispatch/CRUSH seams consult the
         # policy instead of ambient jax.devices() (cephlint CL9).
         from ..common.device_policy import (DevicePolicy,
                                             configure_device_policy)
 
         self.device_policy = configure_device_policy(
             DevicePolicy.from_conf(self.cct.conf))
-        configure_from_conf(self.cct.conf, policy=self.device_policy)
-        # keep the callback so shutdown can deregister it — a stopped
-        # OSD reacting to a later injectargs would flip the
-        # process-wide pool on behalf of a corpse
-        self._pool_observer = lambda _n, v: POOL.configure(
-            enabled=bool(v))
-        self.cct.conf.add_observer(["ec_device_pool"],
-                                   self._pool_observer)
         self.write_batcher.start()
         self.read_batcher.start()
         self._tick_thread = threading.Thread(
@@ -649,14 +631,6 @@ class OSD(
             self.cct.dout("osd", 0,
                           f"{self.whoami} write batcher stop raised: "
                           f"{e!r}")
-        if self._pool_observer is not None:
-            try:
-                self.cct.conf.remove_observer(self._pool_observer)
-            except Exception as e:
-                self.cct.dout(
-                    "osd", 0,
-                    f"{self.whoami} observer removal raised: {e!r}")
-            self._pool_observer = None
         try:
             self.mc.shutdown()
         except Exception as e:
@@ -783,15 +757,6 @@ class OSD(
         if codec is None:
             profile = dict(self.osdmap.ec_profiles.get(name) or {})
             profile.setdefault("plugin", "jax")
-            # ec_kernel: 'oracle'/'numpy' swap the whole backend for the
-            # default plugin; 'xla'/'pallas' pick the GF kernel inside
-            # the jax backend (process-wide, mirrors CEPH_TPU_EC_KERNEL)
-            kern = str(self.cct.conf.get("ec_kernel"))
-            if kern in ("oracle", "numpy") and profile["plugin"] == "jax":
-                profile["plugin"] = kern
-            elif kern in ("xla", "pallas"):
-                from ..ops.bitplane import set_kernel_override
-                set_kernel_override(kern)
             codec = ErasureCodePluginRegistry.instance().factory(profile)
             self._codecs[name] = codec
         return codec

@@ -14,7 +14,7 @@ import numpy as np
 
 from ..common.crc32c import crc32c
 from ..common.failpoint import FailpointCrash, FailpointError, failpoint
-from ..common.tracer import TRACER, op_trace, trace_now
+from ..common.tracer import TRACER, op_trace, stage, trace_now
 from ..store.object_store import NotFound, Transaction
 from .messages import (
     MECSubOpRead,
@@ -1017,9 +1017,9 @@ class ECBackendMixin:
         available chunks in sorted order — the exact selection
         `RSCodec.decode_chunks` makes, so the windowed bytes are
         bit-identical to full-decode-then-slice.  The apply is fused
-        with the flush's other decodes by the read batcher (pooled
-        commit + one dispatch); bit-column locality makes the column
-        slice exact."""
+        with the flush's other decodes by the read batcher (one commit,
+        one dispatch); bit-column locality makes the column slice
+        exact."""
         rows = tuple(sorted(use))[:k]
         if len(rows) < k:
             return None
@@ -1031,17 +1031,14 @@ class ECBackendMixin:
         if rb is not None:
             out = rb.decode(dm, stack, dm_key)
         else:
-            from ..ops.bitplane import apply_matrix_jax
-            from ..ops.device_pool import POOL
+            import jax.numpy as jnp
 
-            dev = POOL.put(stack) if POOL.enabled() else stack
-            try:
-                out = np.asarray(  # noqa: CL8 — decoded range serializes straight into the client reply
-                    apply_matrix_jax(dm, dev, mat_key=dm_key),
-                    dtype=np.uint8)
-            finally:
-                if dev is not stack:
-                    POOL.release(dev)
+            from ..ops.bitplane import apply_matrix_jax
+
+            with stage("gf.h2d"):
+                dev = jnp.asarray(stack, dtype=jnp.uint8)
+            out = np.asarray(  # noqa: CL8 — decoded range serializes straight into the client reply
+                apply_matrix_jax(dm, dev, mat_key=dm_key), dtype=np.uint8)
         return {i: out[i] for i in range(k)}
 
     # .. cephread: hot-object cache plumbing ...............................
@@ -1119,7 +1116,7 @@ class ECBackendMixin:
                     result=f"unreadable: only {len(avail_probe)} chunks",
                 )
             # zero-copy views over the gathered chunk bytes — the host
-            # copies happen at the pooled decode seam below, not here
+            # copies happen at the decode seam below, not here
             chunks = {
                 s: np.frombuffer(b, dtype=np.uint8)
                 for s, b in avail_probe.items()

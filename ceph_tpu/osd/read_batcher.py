@@ -21,11 +21,9 @@ both seams across concurrent ops:
 
 - **Decode coalescing**: degraded stripes decode through the codec's
   CACHED decode matrix (``_decode_entry``), and all stripes of a flush
-  sharing a matrix fuse along the byte-column axis into ONE pooled
-  ``apply_matrix_jax`` dispatch — the input stacks commit through
-  ``ops/device_pool.py`` (client reads now pool like recovery's
-  ``decode_chunks`` already did), and per-op column windows are demuxed
-  back bit-identically.  GF matrix application is byte-column-local
+  sharing a matrix fuse along the byte-column axis into ONE
+  ``apply_matrix_jax`` dispatch over one committed stack, and per-op
+  column windows are demuxed back bit-identically.  GF matrix application is byte-column-local
   (the same property the write batcher and the RMW parity delta rest
   on), so fusing changes scheduling, never bytes.
 
@@ -40,7 +38,7 @@ Ops fall back to the historical inline path when coalescing is off
 (window 0, stopped, a ``crash`` failpoint latched the batcher off) or
 the backend sentinel has latched degraded — reads must keep flowing on
 a sick accelerator, so a degraded sentinel bypasses the batch plane
-entirely rather than trusting a pooled decode.
+entirely rather than trusting a batched decode.
 
 Fault injection: ``osd.read_batcher.gather`` fires at the head of every
 flush.  ``error`` fails EVERY op in the batch (each re-runs inline or
@@ -58,7 +56,7 @@ from ..common.failpoint import FailpointCrash, failpoint
 from ..common.kernel_telemetry import SENTINEL, TELEMETRY
 from ..common.lockdep import make_lock
 from ..common.throttle import Throttle
-from ..common.tracer import TRACER, op_trace, trace_now
+from ..common.tracer import TRACER, op_trace, stage, trace_now
 from .messages import unpack_data
 
 
@@ -219,14 +217,6 @@ class ReadBatcher:
             self._flush_asap = True
             self._cond.notify_all()
 
-    def _use_pool(self) -> bool:
-        from ..ops.device_pool import POOL
-
-        if self._cct is not None \
-                and not bool(self._cct.conf.get("ec_device_pool")):
-            return False
-        return POOL.enabled()
-
     # -- submit: gathers ---------------------------------------------------
     def gather(self, pgid, acting, reqs: list[ReadReq],
                est_bytes: int) -> dict:
@@ -259,7 +249,7 @@ class ReadBatcher:
         """Blocking convenience: [rows, W] surviving-chunk stack in,
         [k, W] decoded data out, bit-identical to
         ``apply_matrix_jax(dm, stack)``; all decodes of a flush sharing
-        `dm` fuse into one pooled dispatch."""
+        `dm` fuse into one dispatch."""
         return self.decode_wait(self.decode_submit(dm, stack, dm_key))
 
     def decode_submit(self, dm: np.ndarray, stack: np.ndarray,
@@ -347,7 +337,7 @@ class ReadBatcher:
     # -- inline fallback ---------------------------------------------------
     def _run_inline(self, p: _PendingRead) -> None:
         """Historical per-op path, on the submitting thread: a gather
-        fans out alone, a decode is one solo pooled dispatch.  Also the
+        fans out alone, a decode is one solo dispatch.  Also the
         recovery path for ops a flush failpoint erred out — bytes from
         here are the referee the batched path must match."""
         with self._lock:
@@ -532,21 +522,20 @@ class ReadBatcher:
 
     # -- decode execution --------------------------------------------------
     def _run_decodes(self, decodes: list[_PendingRead]) -> None:
-        """One fused pack -> pooled apply -> demux per decode-matrix
-        group.  Stacks sharing a matrix concat along the column axis
-        (variable widths are fine — demux walks cumulative offsets);
-        the packed stack commits through the device pool and the single
+        """One fused pack -> apply -> demux per decode-matrix group.
+        Stacks sharing a matrix concat along the column axis (variable
+        widths are fine — demux walks cumulative offsets); the single
         ``np.asarray`` per group is the deliberate reply-serialization
         sync — decoded bytes go straight into a client reply, there is
         nothing downstream to keep device-resident for."""
-        from ..ops.bitplane import apply_matrix_served
-        from ..ops.device_pool import POOL
+        import jax.numpy as jnp
+
+        from ..ops.bitplane import apply_matrix_jax, current_backend
 
         groups: dict[object, list[_PendingRead]] = {}
         for p in decodes:
             key = p.dm_key if p.dm_key is not None else p.dm.tobytes()
             groups.setdefault((key, p.stack.shape[0]), []).append(p)
-        use_pool = self._use_pool()
         t0 = time.perf_counter()
         bytes_in = 0
         host_copy = 0
@@ -558,16 +547,12 @@ class ReadBatcher:
             if len(ps) > 1:
                 host_copy += packed.nbytes
             bytes_in += packed.nbytes
-            dev = POOL.put(packed) if use_pool else packed
-            try:
-                out_dev, backend = apply_matrix_served(
-                    dm, dev, mat_key=ps[0].dm_key)
-                backends.add(backend)
-                out = np.asarray(  # noqa: CL8 — the decoded bytes serialize into client replies; this is the one deliberate read-plane sync
-                    out_dev, dtype=np.uint8)
-            finally:
-                if dev is not packed:
-                    POOL.release(dev)
+            with stage("gf.h2d"):
+                dev = jnp.asarray(packed, dtype=jnp.uint8)
+            out_dev = apply_matrix_jax(dm, dev, mat_key=ps[0].dm_key)
+            backends.add(current_backend())
+            out = np.asarray(  # noqa: CL8 — the decoded bytes serialize into client replies; this is the one deliberate read-plane sync
+                out_dev, dtype=np.uint8)
             host_copy += out.nbytes
             c = 0
             for p in ps:

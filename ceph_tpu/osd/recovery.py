@@ -238,6 +238,24 @@ class RecoveryMixin:
         # authoritative-log pull, the per-peer classification, and
         # delete propagation
         peers: dict[tuple[int, int], tuple[int, list]] = {}
+        my_shard = acting.index(self.id) if is_ec else 0
+        my_cid = self._cid(pg.pgid, my_shard)
+
+        def _my_oids() -> set:
+            try:
+                return {
+                    o for o in self.store.list_objects(my_cid)
+                    if not o.startswith("_")
+                }
+            except (NotFound, KeyError):
+                return set()
+
+        # the answers are a snapshot taken without pg.lock: client writes
+        # can land before the push phase below takes it.  Peers are
+        # judged against this PG as it stood when they were asked.
+        with pg.lock:
+            version_at_query = pg.version
+            my_oids = _my_oids()
         peer_epochs: list[int] = []
         t_peer0 = trace_now()
         queried = 0
@@ -363,15 +381,6 @@ class RecoveryMixin:
             }
         if ahead:
             (_b_shard, b_osd), _bv = max(ahead.items(), key=lambda kv: kv[1])
-            my_shard = acting.index(self.id) if is_ec else 0
-            try:
-                my_oids = [
-                    o for o in self.store.list_objects(
-                        self._cid(pg.pgid, my_shard))
-                    if not o.startswith("_")
-                ]
-            except (NotFound, KeyError):
-                my_oids = []
             tid = self._next_tid()
             # span opened BEFORE the send so the MPGPull carries its id
             # as parent — the donor's rebuild/push spans join THIS node
@@ -385,7 +394,7 @@ class RecoveryMixin:
                 self._conn_to_osd(b_osd).send_message(MPGPull(
                     tid=tid, pgid=pg.pgid, shard=my_shard,
                     from_version=pg.version, epoch=self.my_epoch(),
-                    have_oids=my_oids,
+                    have_oids=sorted(_my_oids()),
                     trace_id=(pull_span.trace_id
                               if pull_span is not None else None),
                     parent_span=(pull_span.span_id
@@ -404,6 +413,9 @@ class RecoveryMixin:
                     f"{self.whoami} pulled {pg.pgid} forward to "
                     f"v{pg.version} from osd.{b_osd}",
                 )
+                # judge peers against the adopted log
+                version_at_query = pg.version
+                my_oids = _my_oids()
             else:
                 return  # retry next tick; judging peers now would be wrong
         # peered: no peer is ahead (or we just adopted the ahead log) —
@@ -411,19 +423,6 @@ class RecoveryMixin:
         pg.activated_interval = interval_at_entry
         if pg.version == 0:
             return  # nothing written yet
-        my_shard = acting.index(self.id) if is_ec else 0
-        my_cid = self._cid(pg.pgid, my_shard)
-
-        def _my_oids() -> set:
-            try:
-                return {
-                    o for o in self.store.list_objects(my_cid)
-                    if not o.startswith("_")
-                }
-            except (NotFound, KeyError):
-                return set()
-
-        my_oids = _my_oids()
         # phase 0.5 — SELF role-heal: an acting permutation can hand this
         # primary a shard role it never held; every peer below is judged
         # against MY collection, so an empty one would read as
@@ -435,7 +434,11 @@ class RecoveryMixin:
         peer_union: set = set()
         for (_v, oids) in peers.values():
             peer_union.update(oids)
-        if peer_union - my_oids:
+        missing_here = peer_union - my_oids
+        if missing_here:
+            # objects written since the snapshot reached the answers too
+            missing_here -= _my_oids()
+        if missing_here:
             donor = next(
                 (osd for (shard, osd), (v, _o) in peers.items()
                  if v >= pg.version),
@@ -445,7 +448,7 @@ class RecoveryMixin:
                 self.cct.dout(
                     "osd", 1,
                     f"{self.whoami} self role-heal {pg.pgid} shard "
-                    f"{my_shard}: {len(peer_union - my_oids)} objects "
+                    f"{my_shard}: {len(missing_here)} objects "
                     f"from osd.{donor}",
                 )
                 tid = self._next_tid()
@@ -460,7 +463,7 @@ class RecoveryMixin:
                     self._conn_to_osd(donor).send_message(MPGPull(
                         tid=tid, pgid=pg.pgid, shard=my_shard,
                         from_version=0, epoch=self.my_epoch(),
-                        have_oids=sorted(my_oids),
+                        have_oids=sorted(_my_oids()),
                         trace_id=(heal_span.trace_id
                                   if heal_span is not None else None),
                         parent_span=(heal_span.span_id
@@ -472,7 +475,9 @@ class RecoveryMixin:
                 self._bg_stage("recovery_pull", t_heal0, trace_now(),
                                span=heal_span, donor=f"osd.{donor}",
                                role_heal=True)
-                my_oids = _my_oids()
+                # what the peers held when asked, as the donor left it;
+                # objects created since are no peer's to miss
+                my_oids = _my_oids() & (my_oids | peer_union)
         # cephheal pg_stats: object-copies this PG's LIVE peers are
         # missing (down/absent shards are counted live by _mgr_report
         # from its store walk — this is the recoverable-by-push half
@@ -493,12 +498,24 @@ class RecoveryMixin:
         # push phase: serialize vs concurrent client writes on this PG
         all_clean = True
         with pg.lock:
+            raced = pg.version != version_at_query
+            my_now = _my_oids() if raced else my_oids
             for (shard, osd), (peer_ver, peer_oids) in peers.items():
-                role_missing = my_oids - set(peer_oids)
-                if peer_ver >= pg.version and not role_missing:
+                # objects deleted since the query are not missing
+                role_missing = (my_oids - set(peer_oids)) & my_now
+                current = peer_ver >= version_at_query
+                if current and not role_missing:
+                    if raced:
+                        # current when asked: the writes since reached
+                        # it through the write path.  Pushing from its
+                        # stale answer would rewrite chunks it already
+                        # holds (and race a scrub); the next pass asks
+                        # again, and a sub-write it missed leaves it
+                        # behind there.
+                        all_clean = False
                     continue  # clean
                 all_clean = False
-                if peer_ver >= pg.version:
+                if current:
                     # version-current but the SHARD ROLE's objects are
                     # absent: an acting-set permutation (OSD out -> CRUSH
                     # reshuffle) handed this OSD a shard it never held —
@@ -515,7 +532,7 @@ class RecoveryMixin:
                     self._push_objects(
                         pg, codec, acting, shard if is_ec else 0, osd,
                         {o: None for o in sorted(role_missing)},
-                        set(peer_oids) - my_oids, is_ec,
+                        set(peer_oids) - my_now, is_ec,
                     )
                     self._bg_stage("recovery_push", t_rb0, trace_now(),
                                    peer=f"osd.{osd}", shard=shard,

@@ -39,26 +39,19 @@ blocked op holds its slot in the client's ``objecter_inflight_ops`` /
 propagates all the way back and new client writes block at admission,
 not mid-pipeline.
 
-A flush larger than ``ec_batch_max_bytes`` (shutdown drains, bursty
-arrivals) is split on stripe boundaries and streamed through
-``ops.pipeline.stream_encode`` so host->device DMA of device-batch i+1
-overlaps the kernel computing device-batch i.
-
-cephdma — the fully async encode path: with the device-resident stripe
-pool on (``ec_device_pool``, default; ``ops/device_pool.py``) a flush
-packs stripes straight into pooled device buffers (device-side concat —
-no host staging copy), encodes through the DONATED jit (the packed
-buffer's storage is recycled for the kernel's output where the backend
-supports donation), demuxes per-stripe parity as device-side slices,
-and completes WITHOUT materializing anything on the host — the single
-deliberate sync is each op's ``encode_wait`` (the commit point), which
-fetches just its own slice and returns dead device buffers to the pool.
-Kernel telemetry separates the two seams: ``ec_batch_flush`` carries
-the flush's host-copy bytes (pool ON: transfers only; OFF: pack +
-transfer + fetch — the control the ci_gate compares), ``encode_wait``
-carries the commit-point sync bytes.  The pool is bypassed — the
-historical synchronous path — when ``ec_device_pool=false`` or the
-backend sentinel has latched degraded.
+Two encode paths, by flush size.  A flush larger than
+``ec_batch_max_bytes`` (shutdown drains, bursty arrivals) is split on
+stripe boundaries and streamed through ``ops.pipeline.stream_encode``
+so host->device DMA of device-batch i+1 overlaps the kernel computing
+device-batch i.  Every other flush is one host concat and one
+``apply_matrix_jax`` whose parity stays on the device: the flush
+completes WITHOUT materializing anything on the host, and the single
+deliberate sync is the first ``encode_wait`` (the commit point) of the
+flush, which fetches the whole flush's parity once — the transfer
+already started by a ``copy_to_host_async`` prefetch — and hands each
+op its own column window.  Kernel telemetry separates the two seams:
+``ec_batch_flush`` carries the flush's host-copy bytes (the concat and
+the transfer), ``encode_wait`` the commit-point fetch.
 
 Fault injection: the ``osd.write_batcher.flush`` failpoint fires at the
 head of every flush.  ``error`` fails EVERY op in the batch (none acks
@@ -82,13 +75,12 @@ from ..common.tracer import TRACER, op_trace, stage, trace_now
 
 
 class _FlushRef:
-    """One pooled flush's device-resident parity: the fused [m, B*L]
-    parent buffer plus the shared commit state.  The FIRST op to reach
-    its encode_wait materializes the whole parent in ONE fetch (a
-    single sync + host copy per flush, not per stripe — per-stripe
-    device slicing was measured to drown CPU dispatch), caches the host
-    array for its batch-mates, and returns the parent buffer to the
-    device pool."""
+    """One flush's device-resident parity: the fused [m, B*L] parent
+    buffer plus the shared commit state.  The FIRST op to reach its
+    encode_wait materializes the whole parent in ONE fetch (a single
+    sync + host copy per flush, not per stripe — per-stripe device
+    slicing was measured to drown CPU dispatch) and caches the host
+    array for its batch-mates."""
 
     #: bound on waiting out another op's in-flight fetch
     FETCH_TIMEOUT = 60.0
@@ -128,8 +120,6 @@ class _FlushRef:
         (the async path surfaces deferred device errors HERE) is latched
         and re-raised to every batch-mate.  Returns (host parity of the
         whole flush, did-I-pay-for-the-fetch)."""
-        from ..ops.device_pool import POOL
-
         if self.host is None and self.error is None \
                 and self._claim.acquire(blocking=False):
             try:
@@ -146,10 +136,7 @@ class _FlushRef:
                         raise
                     self.fetch_bytes = host.nbytes
                     self.host = host
-                    # broadcast BEFORE the pool bookkeeping: 63 batch-
-                    # mates may be parked on this event
                     self._ready.set()
-                    POOL.release(dev)
                     return host, True
             finally:
                 self._claim.release()
@@ -162,9 +149,9 @@ class _FlushRef:
 
 
 class _DevParity:
-    """A stripe's parity still resident on device (the pooled async
-    path): column window [c0, c1) of its flush's fused parity,
-    materialized host-side only at the op's encode_wait."""
+    """A stripe's parity still resident on device: column window
+    [c0, c1) of its flush's fused parity, materialized host-side only
+    at the op's encode_wait."""
 
     __slots__ = ("ref", "c0", "c1", "rows")
 
@@ -359,17 +346,6 @@ class WriteBatcher:
             self._flush_asap = True
             self._cond.notify_all()
 
-    def _use_pool(self) -> bool:
-        """Pooled async flush path usable right now: the runtime escape
-        hatch (``ec_device_pool``) AND the process-wide pool's own gate
-        (configured on, sentinel not degraded)."""
-        from ..ops.device_pool import POOL
-
-        if self._cct is not None \
-                and not bool(self._cct.conf.get("ec_device_pool")):
-            return False
-        return POOL.enabled()
-
     # -- submit ------------------------------------------------------------
     def encode_chunks(self, mat: np.ndarray, chunks: np.ndarray,
                       mat_key: str | None = None) -> np.ndarray:
@@ -491,12 +467,11 @@ class WriteBatcher:
     def encode_wait(self, p: _PendingStripe) -> np.ndarray:
         """Block for a ticket's parity (or raise its batch's error).
 
-        THE commit point of the async encode path: a pooled flush left
-        this op's parity device-resident, and the ``np.asarray`` here is
-        the one deliberate host materialization — per op, off the
+        THE commit point of the async encode path: a flush left this
+        op's parity device-resident, and the ``np.asarray`` here is the
+        one deliberate host materialization — once per flush, off the
         flusher thread, accounted as the ``encode_wait`` sync-point
-        kernel record.  The last stripe of a flush to commit returns the
-        flush's parity buffer to the device pool."""
+        kernel record."""
         try:
             if not p.event.wait(timeout=self.OP_TIMEOUT):
                 raise TimeoutError(
@@ -539,6 +514,8 @@ class WriteBatcher:
     def _inline(self, mat: np.ndarray, chunks: np.ndarray,
                 tctx=None, tracked=None,
                 mat_key: str | None = None) -> np.ndarray:
+        import jax.numpy as jnp
+
         from ..ops.bitplane import apply_matrix_jax
 
         with self._lock:
@@ -549,8 +526,10 @@ class WriteBatcher:
         with stage(
             "ec_encode_inline", (tctx.trace_id,) if tctx is not None else ()
         ):
+            with stage("gf.h2d"):
+                dev = jnp.asarray(chunks, dtype=jnp.uint8)
             parity = np.asarray(  # noqa: CL8 — inline per-op encode is deliberately synchronous
-                apply_matrix_jax(mat, chunks, mat_key=mat_key),
+                apply_matrix_jax(mat, dev, mat_key=mat_key),
                 dtype=np.uint8)
         if tctx is not None:
             TRACER.record(tctx, "encode", entity=self._entity,
@@ -671,10 +650,10 @@ class WriteBatcher:
                     flush_id=fid, stripes=len(batch), fan_in=fan_in,
                 )
         if err is None:
-            # pooled flushes: start each parity parent's D2H in the
-            # background so commit fetches land on warm bytes.  MUST
-            # run before _complete — once events are set an op may
-            # consume the parent (fetch swaps it out and recycles it)
+            # start each parity parent's D2H in the background so
+            # commit fetches land on warm bytes.  MUST run before
+            # _complete — once events are set an op may consume the
+            # parent (fetch swaps it out)
             seen_refs: set[int] = set()
             for _p, r in results:
                 if isinstance(r, _DevParity) and id(r.ref) not in seen_refs:
@@ -695,14 +674,11 @@ class WriteBatcher:
                                   time.perf_counter() - t0)
                 self._logger.hinc("stage_encode", w1 - w0)
             if TELEMETRY.enabled:
-                # pool OFF: the flush fetched every parity slice, a
-                # true sync point — honest achieved GiB/s for the fused
-                # pack -> encode -> scatter.  Pool ON: dispatch is
-                # async (synced=False, the record measures the queue;
-                # the commit-point sync rides the per-op `encode_wait`
-                # record instead), and host_copy carries only the
-                # copies THIS flush actually performed — the
-                # control-vs-pool delta the ci_gate smoke compares.
+                # a streamed burst fetched its parity here, a true sync
+                # point; otherwise dispatch is async (synced=False, the
+                # record measures the queue; the commit-point sync
+                # rides the `encode_wait` record instead).  host_copy
+                # carries only the copies THIS flush performed.
                 TELEMETRY.record(
                     "ec_batch_flush", "+".join(sorted(backends)),
                     time.perf_counter() - t0, bytes_in=nbytes,
@@ -715,19 +691,23 @@ class WriteBatcher:
         """One fused pack -> encode -> scatter per (matrix, L) group.
 
         Returns (results, host_copy_bytes, synced, backends), backends
-        being the GF kernels that served the groups.  With the device pool
-        ON the results are `_DevParity` slices still resident on device
-        (nothing materialized — host_copy counts only the host->device
-        stripe commits and synced stays False, the dispatch is async);
-        with it OFF this is the historical synchronous path (host pack
-        copy + packed transfer + full parity fetch, all counted, synced
-        True).  Parity bytes are bit-identical either way — pooling
-        changes scheduling and allocation, never results."""
+        being the GF kernels that served the groups.  A group over
+        ``ec_batch_max_bytes`` streams through stream_encode and its
+        results are host parity (synced True); every other group is one
+        host concat, zero-padded to a power-of-two stripe count, and one
+        apply whose results are `_DevParity` slices still resident on
+        device (synced stays False, the dispatch is async).  host_copy
+        counts the host concat and the host->device transfer of each
+        group, pads included.  Parity bytes are bit-identical either
+        way."""
+        import jax.numpy as jnp
+
+        from ..ops.bitplane import apply_matrix_jax, current_backend
+
         groups: dict[tuple, list[_PendingStripe]] = {}
         for p in batch:
             groups.setdefault(p.key, []).append(p)
         max_bytes = self._max_bytes()
-        use_pool = self._use_pool()
         host_copy = 0
         synced = False
         backends: set[str] = set()
@@ -735,73 +715,57 @@ class WriteBatcher:
         for (_gkey, L), ps in groups.items():
             mat = ps[0].mat
             stripe_b = ps[0].chunks.nbytes
-            group_b = sum(p.chunks.nbytes for p in ps)
-            if max_bytes > 0 and len(ps) > 1 and group_b > max_bytes:
+            n = len(ps)
+            if max_bytes > 0 and n > 1 and n * stripe_b > max_bytes:
                 # burst bigger than one device batch: split on stripe
-                # boundaries and double-buffer DMA against compute
-                # (stream_encode pools its own transfers; its result
-                # fetches make this group a sync point either way)
-                from ..ops.bitplane import current_backend
+                # boundaries and double-buffer DMA against compute (its
+                # result fetches make this group a sync point)
                 from ..ops.pipeline import stream_encode
 
                 packed = np.concatenate([p.chunks for p in ps], axis=1)
+                host_copy += packed.nbytes
+
                 spd = max(1, max_bytes // stripe_b)
 
-                def dev_batches(packed=packed, L=L, n=len(ps), spd=spd):
+                def dev_batches(packed=packed, L=L, n=n, spd=spd):
                     for i in range(0, n, spd):
                         yield packed[:, i * L:(i + spd) * L]
 
-                outs = stream_encode(mat, dev_batches(), kernel="auto",
+                outs = stream_encode(mat, dev_batches(),
                                      mat_key=ps[0].mat_key)
                 backends.add(current_backend())
                 parity = np.concatenate(outs, axis=1)
                 # only THIS seam's own copies (the two host concats):
                 # the transfers and result fetches are counted by the
                 # stream_encode record — each seam counts its own
-                host_copy += packed.nbytes + parity.nbytes
+                host_copy += parity.nbytes
                 synced = True
                 for i, p in enumerate(ps):
                     out.append((p, parity[:, i * L:(i + 1) * L]))
                 continue
-            if use_pool:
-                # cephdma pooled async path: commit + concat + encode
-                # fuse into ONE dispatch (no host staging pack — the
-                # stripes' committed buffers are donated straight into
-                # the kernel), parity stays device-resident; the op's
-                # encode_wait owns the single deliberate sync, and the
-                # parent parity buffer recycles through the pool there
-                from ..ops.bitplane import fused_bucket, fused_encode_async
-
-                parity_dev, backend = fused_encode_async(
-                    mat, [p.chunks for p in ps],
-                    mat_key=ps[0].mat_key, donate=True)
-                backends.add(backend)
-                # the host->device stripe commits — charged at the
-                # dispatched arity (zero-stripe pads transfer too)
-                host_copy += fused_bucket(len(ps)) * stripe_b
-                ref = _FlushRef(parity_dev, backend)
-                m_rows = mat.shape[0]
-                for i, p in enumerate(ps):
-                    out.append((p, _DevParity(
-                        ref, i * L, (i + 1) * L, m_rows)))
-                continue
-            # historical synchronous path (ec_device_pool=false escape
-            # hatch / sentinel-degraded backend): host pack, transfer,
-            # full parity fetch right here on the flusher
-            from ..ops.bitplane import apply_matrix_served
-
-            packed = (ps[0].chunks if len(ps) == 1 else
-                      np.concatenate([p.chunks for p in ps], axis=1))
-            parity_dev, backend = apply_matrix_served(
-                mat, packed, mat_key=ps[0].mat_key)
+            # zero stripes pad the count to a power of two: every count
+            # is a compile of its own, and on the XLA backend each new
+            # one stalled the write tail by 100 ms+
+            bucket = 1 << (n - 1).bit_length()
+            if bucket == 1:
+                packed = ps[0].chunks
+            else:
+                parts = [p.chunks for p in ps]
+                if bucket > n:
+                    parts.append(np.zeros(
+                        (parts[0].shape[0], (bucket - n) * L), np.uint8))
+                packed = np.concatenate(parts, axis=1)
+                host_copy += packed.nbytes
+            with stage("gf.h2d"):
+                dev = jnp.asarray(packed, dtype=jnp.uint8)
+            parity_dev = apply_matrix_jax(mat, dev, mat_key=ps[0].mat_key)
+            backend = current_backend()
             backends.add(backend)
-            parity = np.asarray(  # noqa: CL8 — the pool-off flush IS the sync point
-                parity_dev, dtype=np.uint8)
-            host_copy += (packed.nbytes if len(ps) > 1 else 0) \
-                + packed.nbytes + parity.nbytes
-            synced = True
+            host_copy += packed.nbytes  # the host->device transfer
+            ref = _FlushRef(parity_dev, backend)
+            m_rows = mat.shape[0]
             for i, p in enumerate(ps):
-                out.append((p, parity[:, i * L:(i + 1) * L]))
+                out.append((p, _DevParity(ref, i * L, (i + 1) * L, m_rows)))
         return out, host_copy, synced, backends
 
     def _complete(self, batch: list[_PendingStripe],

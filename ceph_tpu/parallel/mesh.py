@@ -24,7 +24,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..common.device_policy import get_device_policy, mesh_over
-from ..ops.bitplane import _apply_bitmatrix, bitmatrix_device
+from ..ops.bitplane import _apply_bitmatrix, _bitmatrix_for
 
 # jax.shard_map graduated from jax.experimental at 0.4.x boundaries and
 # renamed its replication-check kwarg (check_rep -> check_vma) on the
@@ -60,7 +60,7 @@ def sharded_apply_matrix(mesh: Mesh, mat: np.ndarray, chunks) -> jax.Array:
     collectives are inserted (verified by the multichip dryrun).
     """
     mat = np.ascontiguousarray(mat, dtype=np.uint8)
-    B = bitmatrix_device(mat.tobytes(), mat.shape)
+    B = _bitmatrix_for(mat)
     chunks = jnp.asarray(chunks, dtype=jnp.uint8)
     in_shard = NamedSharding(mesh, P(None, LEN_AXIS))
     rep = NamedSharding(mesh, P(None, None))
@@ -81,7 +81,7 @@ def distributed_decode(mesh: Mesh, decode_mat: np.ndarray, shards) -> jax.Array:
     """
     k, L = shards.shape
     mat = np.ascontiguousarray(decode_mat, dtype=np.uint8)
-    B = bitmatrix_device(mat.tobytes(), mat.shape)
+    B = _bitmatrix_for(mat)
     shards = jnp.asarray(shards, dtype=jnp.uint8)
     row_mesh = mesh_over(mesh.devices, ROW_AXIS)
     n = row_mesh.devices.size

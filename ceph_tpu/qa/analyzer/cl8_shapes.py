@@ -37,12 +37,12 @@ plus ``cl8_hostcopy_files`` (osd/write_batcher.py, osd/ec_backend.py)
 is scanned for explicit host<->device traffic: ``jax.device_get`` /
 ``jax.device_put`` / ``.block_until_ready()`` calls, and
 ``np.asarray``/``np.array`` wrapped directly around a device-producing
-kernel entry point (``apply_matrix_jax`` and friends — the
-materialize-at-the-callsite idiom the device pool exists to kill).
-The contract is drive-to-zero: a deliberate sync or transfer seam (the
-pool's own ``device_put``, an op's commit-point fetch, the pool-off
-historical flush) carries an explicit ``# noqa: CL8`` with its reason;
-everything else is a finding.  Baseline growth is a regression.
+kernel entry point (``apply_matrix_jax``, ``apply_matrix_pallas``,
+``_apply_bitmatrix`` — the materialize-at-the-callsite idiom).
+The contract is drive-to-zero: a deliberate sync seam (an op's
+commit-point fetch, a decoded range serialized into a client reply)
+carries an explicit ``# noqa: CL8`` with its reason; everything else
+is a finding.  Baseline growth is a regression.
 
 Weak-typed Python scalars adopt the array side's dtype (JAX semantics)
 and never report.  ``# noqa: CL8`` / baseline.toml suppress as usual.
@@ -154,9 +154,7 @@ def _broadcast(a: tuple | None, b: tuple | None):
 #: device-producing kernel entry points: np.asarray(<one of these>(...))
 #: is a host materialization of a device result at the callsite
 _DEVICE_PRODUCERS = {
-    "apply_matrix_jax", "apply_xor_matrix_jax", "apply_matrix_dev",
-    "apply_xor_matrix_dev", "apply_matrix_xla", "apply_matrix_pallas",
-    "_apply_bitmatrix", "_apply_bitmatrix_donated",
+    "apply_matrix_jax", "apply_matrix_pallas", "_apply_bitmatrix",
 }
 _MATERIALIZERS = {"asarray", "array"}
 
@@ -220,9 +218,10 @@ def _audit_host_trips(mod: ModuleInfo, traced_fns: set) -> list[Finding]:
             if cn in ("device_get", "device_put", "block_until_ready"):
                 report(node, f"{owner}:{cn}",
                        f"[{owner}] explicit host<->device traffic "
-                       f"({cn}) on the op path — route through the "
-                       f"device pool / async seams, or mark the "
-                       f"deliberate sync with a reasoned noqa")
+                       f"({cn}) on the op path — commit with "
+                       f"jnp.asarray under gf.h2d and fetch at the "
+                       f"commit point, or mark the deliberate sync "
+                       f"with a reasoned noqa")
                 continue
             if cn in _MATERIALIZERS and node.args \
                     and isinstance(node.args[0], ast.Call):
@@ -232,9 +231,9 @@ def _audit_host_trips(mod: ModuleInfo, traced_fns: set) -> list[Finding]:
                            f"[{owner}] {cn}() materializes {inner}'s "
                            f"device result at the callsite — a "
                            f"host-copy sync per call; keep it "
-                           f"device-resident (apply_matrix_dev + "
-                           f"commit-point fetch) or noqa the "
-                           f"deliberate sync")
+                           f"device-resident (apply_matrix_jax's "
+                           f"result + a commit-point fetch) or noqa "
+                           f"the deliberate sync")
 
     # module scope (import-time transfers count too) — own_nodes skips
     # every FunctionDef subtree, so functions are attributed below

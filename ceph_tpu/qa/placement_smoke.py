@@ -1,5 +1,5 @@
 """cephplace CI smoke: placement-plane observability end to end
-(qa/ci_gate.sh step 11; ISSUE 15 acceptance).
+(qa/ci_gate.sh step 10).
 
 Drives the WHOLE surface through the production path, no shortcuts:
 

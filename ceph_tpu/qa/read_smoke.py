@@ -1,5 +1,5 @@
 """cephread CI smoke: the coalesced READ plane end to end (qa/ci_gate.sh
-step 13; ISSUE 17 acceptance).
+step 12).
 
 Five gates, one JSON summary:
 

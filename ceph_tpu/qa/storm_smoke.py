@@ -1,5 +1,5 @@
 """cephstorm CI smoke: a seeded 250-stub failure storm end to end
-(qa/ci_gate.sh step 14; ISSUE 18 acceptance).
+(qa/ci_gate.sh step 13).
 
 One process, no shortcuts on the control plane:
 

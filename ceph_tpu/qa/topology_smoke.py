@@ -1,5 +1,5 @@
 """cephtopo CI smoke: one encode path, three device topologies
-(qa/ci_gate.sh step 12; ISSUE 16 acceptance).
+(qa/ci_gate.sh step 11).
 
 The DevicePolicy refactor's whole claim is that topology is a *value*:
 the same production encode (`parallel.sharded_apply_matrix` through
@@ -10,8 +10,7 @@ policy grants
 2. ``mesh`` — every device of the virtual 8-way host mesh (the
    multi-chip shape, conftest-style);
 3. ``mesh`` with two devices pinned failed — the sentinel-degraded
-   shape: the mesh SHRINKS to the 6 survivors instead of wedging, and
-   the device-pool budget shrinks with it.
+   shape: the mesh SHRINKS to the 6 survivors instead of wedging.
 
 Every device/mesh decision in this smoke routes through DevicePolicy —
 the smoke is itself CL9-clean, which is the point.
@@ -112,22 +111,6 @@ def main() -> int:
         problems.append(
             f"degraded policy still grants pinned-failed devices: "
             f"{sorted(deg_rows & set(bad))}")
-
-    # and the pool budget shrinks with the mesh (per-device share x
-    # live count), instead of survivors inheriting the dead chips' share
-    max_bytes = 8 << 20
-    full_budget = policies[f"mesh-{N_VIRTUAL}"].pool_budget(max_bytes)
-    deg_budget = policies["degraded"].pool_budget(max_bytes)
-    summary["pool_budget"] = {
-        "configured": max_bytes, "full": full_budget, "degraded": deg_budget}
-    if full_budget != max_bytes:
-        problems.append(
-            f"healthy mesh budget {full_budget} != configured {max_bytes}")
-    want_deg = (max_bytes // N_VIRTUAL) * (N_VIRTUAL - PINNED_BAD)
-    if deg_budget != want_deg:
-        problems.append(
-            f"degraded budget {deg_budget} != {want_deg} "
-            f"(per-device share x {N_VIRTUAL - PINNED_BAD} survivors)")
 
     if not problems:
         summary["parity"] = "bit-identical across all topologies"

@@ -48,25 +48,17 @@
 #    0, ceph_recovery_*{pool,codec} series render on the exporter with
 #    a plausible repair ratio (~k for RS), and the tail-promoted
 #    recovery trace tree is connected cross-entity at sampling=0.
-# 10. device pool smoke (ceph_tpu/qa/device_pool_smoke.py): the batcher
-#    traffic run with ec_device_pool=false (control) vs true — fails
-#    unless host-copy bytes per fused flush drop >= 50%, aggregate
-#    throughput does not regress (>= 0.85x control, CPU noise margin),
-#    control flushes are sync points while pooled flushes are async
-#    with their commit sync on the encode_wait record, and parity
-#    buffers recycle through the pool.
-# 11. placement smoke (ceph_tpu/qa/placement_smoke.py): mark an OSD out
+# 10. placement smoke (ceph_tpu/qa/placement_smoke.py): mark an OSD out
 #    under a small live cluster — the placement module's remap forecast
 #    (batched-CRUSH epoch diff, `placement diff`) must match the
 #    observed acting-set churn within 10%, ceph_placement_*/ceph_remap_*
 #    /ceph_balancer_* series must render on the exporter, a balancer
 #    run against a stacked imbalance must commit moves and improve the
 #    exported score, and PG_IMBALANCE must raise then clear.
-# 12. topology smoke (ceph_tpu/qa/topology_smoke.py): the same
+# 11. topology smoke (ceph_tpu/qa/topology_smoke.py): the same
 #    production encode must be bit-identical under a cpu-1, mesh-8, and
-#    sentinel-degraded (two devices pinned failed) DevicePolicy, the
-#    degraded mesh must shrink to the survivors, and the device-pool
-#    budget must shrink with it.  Step 1's cephlint run includes the
+#    sentinel-degraded (two devices pinned failed) DevicePolicy, and the
+#    degraded mesh must shrink to the survivors.  Step 1's cephlint run includes the
 #    CL9/CL10 device-topology & sharding checks that pin the policy
 #    refactor behind this smoke, and the CL11/CL12 determinism +
 #    observability-drift checks (no extra step: the run uses the
@@ -282,26 +274,6 @@ else
     rc=1
 fi
 
-echo "== device pool smoke (control vs pooled async encode) =="
-# host-copy bytes per fused flush must drop >= 50% with the pool on,
-# throughput must not regress, and the flush/commit sync split must be
-# honest (ceph_tpu/qa/device_pool_smoke.py; docs/write_path.md)
-CEPH_TPU_BENCH_FORCE_CPU=1 JAX_PLATFORMS=cpu \
-    python -m ceph_tpu.qa.device_pool_smoke > "$OUT_DIR/device_pool_smoke.json"
-dpool_rc=$?
-if [ $dpool_rc -eq 0 ]; then
-    echo "device pool smoke: ok"
-elif python -c "import json; json.load(open('$OUT_DIR/device_pool_smoke.json'))" \
-        2>/dev/null; then
-    echo "device pool smoke: FAILED:"
-    python -c "import json; [print(' -', p) for p in json.load(open('$OUT_DIR/device_pool_smoke.json'))['problems']]" || true
-    rc=1
-else
-    rm -f "$OUT_DIR/device_pool_smoke.json"
-    echo "device pool smoke: ERROR (exit $dpool_rc) — scenario crashed"
-    rc=1
-fi
-
 echo "== placement smoke (remap forecast + balancer scoring) =="
 # forecast-vs-observed churn on an osd-out, balancer score improvement
 # against a stacked imbalance, PG_IMBALANCE raise/clear, and the
@@ -325,8 +297,8 @@ fi
 
 echo "== topology smoke (cpu-1 / mesh-N / degraded parity) =="
 # the same sharded encode through three injected DevicePolicy variants
-# must be bit-identical, the sentinel-degraded mesh must shrink instead
-# of wedging, and the pool budget must track the survivors
+# must be bit-identical, and the sentinel-degraded mesh must shrink
+# instead of wedging
 # (ceph_tpu/qa/topology_smoke.py; docs/static_analysis.md CL9)
 python -m ceph_tpu.qa.topology_smoke > "$OUT_DIR/topology_smoke.json"
 topo_rc=$?
@@ -387,5 +359,5 @@ else
     rc=1
 fi
 
-echo "Artifacts in $OUT_DIR/ (cephlint.sarif, cephrace.sarif, traffic.json, traffic_trace.json, trace_perfetto.json, health_smoke.json, bench_wedged.json, accounting_smoke.json, qos_smoke.json, recovery_smoke.json, device_pool_smoke.json, placement_smoke.json, topology_smoke.json, read_smoke.json, storm_smoke.json)"
+echo "Artifacts in $OUT_DIR/ (cephlint.sarif, cephrace.sarif, traffic.json, traffic_trace.json, trace_perfetto.json, health_smoke.json, bench_wedged.json, accounting_smoke.json, qos_smoke.json, recovery_smoke.json, placement_smoke.json, topology_smoke.json, read_smoke.json, storm_smoke.json)"
 exit $rc

@@ -117,7 +117,7 @@ class TestKernelTelemetry:
         mat = np.array([[1, 2], [3, 4]], np.uint8)
         batches = [np.random.default_rng(i).integers(
             0, 256, (2, 256), dtype=np.uint8) for i in range(3)]
-        outs = stream_encode(mat, iter(batches), kernel="auto")
+        outs = stream_encode(mat, iter(batches))
         assert len(outs) == 3
         d = TELEMETRY.dump()["stream_encode"]
         assert d["bytes_in"] >= 3 * 512

@@ -19,7 +19,6 @@ import would leave the others unable to collect this file.
 import ast
 import os
 import re
-import warnings
 
 import numpy as np
 import pytest
@@ -190,22 +189,20 @@ def test_straw2_scorer_compiles(one_chip):
 
 
 def test_fused_write_flush_compiles(one_chip):
-    """The batched write flush's fused program: RS(8,4), 64 stripes of
-    4 KiB (512 B chunks), donated, fits one chip."""
+    """The batched write flush's program: RS(8,4), 64 stripes of 4 KiB
+    (512 B chunks) packed on the host into one [8, 32 Ki] block and
+    applied through the production Pallas dispatch (its own padding and
+    layout) in one launch; fits one chip."""
+    import jax
     import jax.numpy as jnp
 
-    from ceph_tpu.gf.matrix import matrix_to_bitmatrix
-    from ceph_tpu.ops.bitplane import _fused_encode_jit
+    from ceph_tpu.ops.pallas_gf import apply_matrix_pallas
 
-    B = matrix_to_bitmatrix(_rs84_encode())
-    with warnings.catch_warnings():
-        # no output aliases a donated stripe: the compiler says so
-        warnings.filterwarnings(
-            "ignore", message="Some donated buffers were not usable")
-        compiled = _fused_encode_jit(64, True).lower(
-            _spec(B.shape, jnp.int8, one_chip),
-            *[_spec((8, 512), jnp.uint8, one_chip) for _ in range(64)],
-        ).compile()
+    mat = np.ascontiguousarray(_rs84_encode(), np.uint8)
+    compiled = jax.jit(lambda x: apply_matrix_pallas(mat, x)).lower(
+        _spec((mat.shape[1], 64 * 512), jnp.uint8, one_chip),
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)

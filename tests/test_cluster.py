@@ -7,6 +7,7 @@ kill/revive/recovery and thrash tests build their own so OSD deaths never
 leak between tests.
 """
 import random
+import threading
 import time
 
 import pytest
@@ -84,6 +85,134 @@ def test_replicated_pool_io(cluster, client):
 def test_mon_command_surface(cluster, client):
     rv, res = client.command({"prefix": "osd dump"})
     assert rv == 0
+
+
+def test_healthy_writes_trigger_no_recovery_push(cluster, client):
+    """Recovery passes run all the time; one whose peer query raced a
+    client write must not re-push what the write path already delivered
+    (every peer answered current, then took the write's sub-op).  On a
+    healthy pool no pass pushes anything."""
+    cluster.create_ec_pool("ecquiet", k=4, m=2)
+    cluster.wait_clean("ecquiet")
+    io = client.open_ioctx("ecquiet")
+    for i in range(16):
+        io.write_full(f"q{i}", bytes([i]) * 3000)
+        io.write(f"q{i}", b"rmw", off=1000)
+    time.sleep(1.0)  # a few more passes over the settled pool
+    pid = client.pool_id("ecquiet")
+    pgs = [pg for osd in cluster.osds.values()
+           for pgid, pg in osd.pgs.items() if pgid.startswith(f"{pid}.")]
+    assert pgs
+    pushed = sum(getattr(pg, "stat_delta_recoveries", 0)
+                 + getattr(pg, "stat_backfills", 0) for pg in pgs)
+    assert pushed == 0, f"{pushed} recovery rounds on a healthy pool"
+    for i in range(16):
+        assert io.read(f"q{i}")[999:1004] == bytes([i]) + b"rmw" + bytes([i])
+
+
+class _WriteBeforePush:
+    """Stands in for a PG's lock on its primary: once a recovery pass of
+    that PG has queried its peers, a client write lands before the pass
+    takes the lock for its push phase, so every such pass races a
+    write."""
+
+    def __init__(self, osd, pg, write):
+        self.inner, self.pg, self.write = pg.lock, pg, write
+        self.armed, self.raced, self.errors = set(), 0, []
+        osd._recover_pg = self._pass(osd._recover_pg)
+        osd._bg_stage = self._stage(osd._bg_stage)
+
+    def _pass(self, recover):
+        def run(pg, *args):
+            try:
+                return recover(pg, *args)
+            finally:
+                self.armed.discard((id(pg), threading.get_ident()))
+        return run
+
+    def _stage(self, bg_stage):
+        def stage(name, *args, **tags):
+            bg_stage(name, *args, **tags)
+            if name == "recovery_peer":
+                self.armed.add((id(self.pg), threading.get_ident()))
+        return stage
+
+    def __enter__(self):
+        key = (id(self.pg), threading.get_ident())
+        if key in self.armed:
+            self.armed.discard(key)
+            if self.pg.activated_interval == self.pg.interval_start:
+                try:
+                    self.write(self.raced)
+                    self.raced += 1
+                except (IOError, ConnectionError) as e:
+                    self.errors.append(e)
+        return self.inner.__enter__()
+
+    def __exit__(self, *exc):
+        return self.inner.__exit__(*exc)
+
+    def __getattr__(self, name):
+        return getattr(self.inner, name)
+
+
+def test_role_backfill_under_steady_writes(cluster, client):
+    """Two version-current OSDs swap shard roles (an upmap permutation)
+    while a client write lands on the PG between every recovery pass's
+    peer query and its push phase: each new role's collection is still
+    rebuilt while the writes go on."""
+    cluster.create_ec_pool("ecswap", k=2, m=2, pg_num=1)
+    cluster.wait_clean("ecswap")
+    io = client.open_ioctx("ecswap")
+    blobs = {f"w{i}": bytes([i]) * 3000 for i in range(12)}
+    for oid, data in blobs.items():
+        io.write_full(oid, data)
+    pid = client.pool_id("ecswap")
+    m = cluster._leader().osdmon.osdmap
+    _up, _upp, acting, primary = m.pg_to_up_acting_osds(pid, 0)
+    i, j = [s for s, o in enumerate(acting) if o != primary][:2]
+    a, b = acting[i], acting[j]
+    x = next(o for o in cluster.osds if o not in acting)
+    posd = cluster.osds[primary]
+    pg = posd.pgs[f"{pid}.0"]
+    racer = _WriteBeforePush(
+        posd, pg, lambda n: io.write_full("hot", n.to_bytes(4, "little")
+                                          * 750))
+    pg.lock = racer
+    try:
+        rv, res = cluster.mon_command({
+            "prefix": "osd pg-upmap-items", "pool": pid, "ps": 0,
+            "mappings": [[a, x], [b, a], [x, b]]})
+        assert rv == 0, (rv, res)
+
+        def roles_filled() -> bool:
+            m = cluster._leader().osdmon.osdmap
+            now = m.pg_to_up_acting_osds(pid, 0)[2]
+            if (now[i], now[j]) != (b, a):
+                return False
+            for shard, osd in ((i, b), (j, a)):
+                try:
+                    held = set(cluster.osds[osd].store.list_objects(
+                        f"{pid}.0s{shard}"))
+                except Exception:
+                    return False
+                if not set(blobs) <= held:
+                    return False
+            return True
+
+        deadline = time.monotonic() + 30.0
+        while not roles_filled():
+            assert time.monotonic() < deadline, (
+                f"shard roles {i}/{j} not backfilled under writes "
+                f"({racer.raced} raced passes)")
+            time.sleep(0.1)
+    finally:
+        pg.lock = racer.inner
+        del posd._recover_pg, posd._bg_stage
+    assert racer.raced and not racer.errors, (racer.raced, racer.errors)
+    cluster.wait_clean("ecswap")
+    for oid, data in blobs.items():
+        assert io.read(oid) == data, oid
 
 
 # -- failure / recovery -----------------------------------------------------

@@ -9,7 +9,8 @@ import numpy as np
 import pytest
 
 from ceph_tpu.ops import bitplane
-from ceph_tpu.ops.bitplane import apply_matrix_jax, apply_matrix_xla
+from ceph_tpu.gf.reference_codec import apply_matrix as ref_apply
+from ceph_tpu.ops.bitplane import apply_matrix_jax
 
 
 @pytest.fixture(autouse=True)
@@ -55,7 +56,9 @@ def test_forced_pallas_dispatches_and_matches_xla(monkeypatch):
     mat = _coding()
     data = np.random.default_rng(1).integers(0, 256, (4, 768), np.uint8)
     got = np.asarray(apply_matrix_jax(mat, data))
-    want = np.asarray(apply_matrix_xla(mat, data))
+    assert called["pallas"] == 1
+    monkeypatch.setenv("CEPH_TPU_EC_KERNEL", "xla")
+    want = np.asarray(apply_matrix_jax(mat, data))
     assert called["pallas"] == 1
     np.testing.assert_array_equal(got, want)
 
@@ -71,7 +74,7 @@ def test_tpu_backend_auto_dispatches_to_pallas(monkeypatch):
 
     def fake(mat, chunks, tile=pallas_gf.DEFAULT_TILE, interpret=False):
         seen["interpret"] = interpret
-        return apply_matrix_xla(mat, chunks)
+        return ref_apply(mat, np.asarray(chunks))
 
     monkeypatch.setattr(pallas_gf, "apply_matrix_pallas", fake)
     mat = _coding()
@@ -149,7 +152,7 @@ def test_xor_matrix_pallas_equivalence(monkeypatch):
     rng = np.random.default_rng(4)
     B = rng.integers(0, 2, (3, 5), np.uint8)
     rows = rng.integers(0, 256, (5, 384), np.uint8)
-    got = np.asarray(bitplane.apply_xor_matrix_jax(B, rows))
+    got = np.asarray(bitplane.apply_matrix_jax(B, rows))
     monkeypatch.setenv("CEPH_TPU_EC_KERNEL", "xla")
-    want = np.asarray(bitplane.apply_xor_matrix_jax(B, rows))
+    want = np.asarray(bitplane.apply_matrix_jax(B, rows))
     np.testing.assert_array_equal(got, want)
