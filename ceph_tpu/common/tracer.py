@@ -106,7 +106,6 @@ READ_STAGES = (
 DEVICE_STAGES = (
     "ec.encode",          # RSCodec.encode_chunks, the whole call
     "ec.decode",          # RSCodec.decode_chunks, the whole call
-    "ec.stack",           # decode's survivor np.stack
     "ec.decode_matrix",   # decode-matrix build on a cache miss
     "gf.h2d",             # commit of a GF apply's input to the device
     "gf.dispatch",        # kron lookup, matrix commits, pad, launch

@@ -143,29 +143,31 @@ class RSCodec(ErasureCode):
         use = avail[: self.k]
         want = sorted(set(want_to_read))
         missing = [w for w in want if w not in chunks]
-        with stage("ec.stack"):
-            shards = np.stack([np.asarray(chunks[r], dtype=np.uint8)
-                               for r in use])
+        survivors = [np.asarray(chunks[r], dtype=np.uint8) for r in use]
         if self.backend == "jax":
-            result = self._rebuild_on_device(use, shards, missing)
+            result = self._rebuild_on_device(use, survivors, missing)
         else:
-            result = self._rebuild_on_host(use, shards, missing)
+            result = self._rebuild_on_host(use, survivors, missing)
         for wanted in want:
             if wanted in chunks:
                 result[wanted] = np.asarray(chunks[wanted], dtype=np.uint8)
         return result
 
-    def _rebuild_on_device(self, use, shards, missing) -> dict:
+    def _rebuild_on_device(self, use, survivors, missing) -> dict:
         """Only the missing shards, in one apply over the survivors and
-        one fetch of [len(missing), L]."""
+        one fetch of [len(missing), L].  The k survivor rows go to the
+        device one by one, unstacked: the apply assembles its input
+        there."""
         if not missing:
             return {}
-        rebuilt = _to_host(self._jax_codec.reconstruct(use, shards, missing))
+        rebuilt = _to_host(
+            self._jax_codec.reconstruct(use, survivors, missing))
         return dict(zip(missing, rebuilt))
 
-    def _rebuild_on_host(self, use, shards, missing) -> dict:
+    def _rebuild_on_host(self, use, survivors, missing) -> dict:
         """The referee backends: all k data rows, then the missing parity
         re-encoded from them."""
+        shards = np.stack(survivors)
         if self.backend == "oracle":
             from ... import native_oracle
 

@@ -20,12 +20,14 @@ sharded across chips by ceph_tpu.parallel.
 
 The seam every codec shares is here, in two steps: a host stripe
 reaches the device by one `jnp.asarray(x, dtype=jnp.uint8)` under the
-`gf.h2d` stage, and `apply_matrix_jax` applies the GF(2^8) matrix to it
-(the fused Pallas kernel on TPU, `_apply_bitmatrix` elsewhere), leaving
-the result on the device for the caller to fetch.  The RS codecs, both
-batchers, the EC backend's ranged decode and the stream pipeline commit
-that way; the SHEC and Clay plugins hand `apply_matrix_jax` host arrays,
-so their transfer is made, and timed, inside `gf.dispatch`.
+`gf.h2d` stage (RS decode commits its k survivors as k 1-D rows, under
+one such stage, with no host stack), and `apply_matrix_jax` applies the
+GF(2^8) matrix to it (the fused Pallas kernel on TPU, `_apply_bitmatrix`
+elsewhere), leaving the result on the device for the caller to fetch.
+The RS codecs, both batchers, the EC backend's ranged decode and the
+stream pipeline commit that way; the SHEC and Clay plugins hand
+`apply_matrix_jax` host arrays, so their transfer is made, and timed,
+inside `gf.dispatch`.
 
 Bit-exactness: all ops are exact integer ops; tests assert parity bytes are
 identical to the C++ oracle (native/gf_oracle.cc).
@@ -158,7 +160,8 @@ def apply_matrix_jax(mat: np.ndarray, chunks,
                      mat_key: str | None = None) -> jnp.ndarray:
     """The GF(2^8) matrix apply: [rows, n] x [n, L] byte chunks (host or
     device) -> [rows, L] device array.  Never blocks: the caller owns
-    the fetch.
+    the fetch.  `chunks` may instead be a sequence of n 1-D uint8 rows
+    of length L, which the apply assembles on the device.
 
     Every codec (rs/shec/clay/lrc plugins, the XOR packet codes, the
     batchers, the stream pipeline) goes through here, so the registry
@@ -193,13 +196,20 @@ def apply_matrix_jax(mat: np.ndarray, chunks,
                 interpret=get_device_policy().backend() != "tpu")
         else:
             backend = "xla"
+            x = (jnp.stack(chunks) if isinstance(chunks, (list, tuple))
+                 else chunks)
             out = _apply_bitmatrix(_bitmatrix_for(mat, mat_key),
-                                   jnp.asarray(chunks, dtype=jnp.uint8))
+                                   jnp.asarray(x, dtype=jnp.uint8))
     if tm.enabled:
-        shape = getattr(chunks, "shape", None)
+        if isinstance(chunks, (list, tuple)):
+            shape = (len(chunks), chunks[0].shape[0])
+            nbytes = sum(int(r.nbytes) for r in chunks)
+        else:
+            shape = getattr(chunks, "shape", None)
+            nbytes = int(getattr(chunks, "nbytes", 0))
         tm.record(
             "gf_apply", backend, time.perf_counter() - t0,
-            bytes_in=int(getattr(chunks, "nbytes", 0)),
+            bytes_in=nbytes,
             bytes_out=mat.shape[0] * shape[-1] if shape else 0,
             compiled=tm.first_call(("gf_apply", mat.shape, shape, backend)),
         )
@@ -260,18 +270,31 @@ class BitplaneCodec:
             self._decode_cache[key] = ent
         return ent
 
+    def _commit(self, shards):
+        """The first k survivors on the device, under one `gf.h2d` stage:
+        one [n, L] array committed whole, or a sequence of 1-D rows of
+        length L committed row by row (no host copy stacks them; the
+        apply reads them on the device).  Rows stay 1-D: a [1, L] uint8
+        array may be tiled to 32 sublanes on the chip."""
+        with stage("gf.h2d"):
+            if isinstance(shards, (list, tuple)):
+                # one batched, asynchronous commit of the uint8 rows: on
+                # a TPU v5e the decode cell's gf.h2d read 1.94 ms a call
+                # so, 2.41 with one jnp.asarray a row
+                return jax.device_put(list(shards[: self.k]))  # noqa: CL8
+            return jnp.asarray(shards, dtype=jnp.uint8)[: self.k]
+
     def decode(self, available_rows, shards) -> jnp.ndarray:
         """Rebuild the k data shards from >= k surviving shards.
 
-        available_rows: shard ids (sorted) matching shards' leading rows.
+        available_rows: shard ids (sorted) matching shards' leading rows;
+        shards: one [n, L] array or a sequence of 1-D uint8 rows.
         """
         rows = tuple(int(r) for r in available_rows)
         if len(rows) < self.k:
             raise ValueError(f"need >= {self.k} shards, got {len(rows)}")
         dm, dm_key = self._decode_entry(rows)
-        with stage("gf.h2d"):
-            shards = jnp.asarray(shards, dtype=jnp.uint8)[: self.k]
-        return apply_matrix_jax(dm, shards, mat_key=dm_key)
+        return apply_matrix_jax(dm, self._commit(shards), mat_key=dm_key)
 
     def _rebuild_entry(self, available_rows, want_rows) -> tuple[np.ndarray, str]:
         key = (tuple(available_rows[: self.k]), tuple(want_rows))
@@ -300,11 +323,11 @@ class BitplaneCodec:
         shard, coding[w - k] times it for a parity shard (reference:
         src/erasure-code/isa/ErasureCodeIsa.cc ::
         ErasureCodeIsaDefault::isa_decode), cached per pattern.
+        `shards` is one [n, L] array or a sequence of 1-D uint8 rows
+        (RS decode passes its k survivors so, unstacked).
         """
         rows = tuple(int(r) for r in available_rows)
         if len(rows) < self.k:
             raise ValueError(f"need >= {self.k} shards, got {len(rows)}")
         rm, rm_key = self._rebuild_entry(rows, [int(w) for w in want_rows])
-        with stage("gf.h2d"):
-            shards = jnp.asarray(shards, dtype=jnp.uint8)[: self.k]
-        return apply_matrix_jax(rm, shards, mat_key=rm_key)
+        return apply_matrix_jax(rm, self._commit(shards), mat_key=rm_key)

@@ -35,8 +35,12 @@ lives at bits row l*kG + (g*k + j); output bit rows are l'*rG + (g*rows +
 i).  The stacking is G column slices and one concat (and the output
 unstacks the same way): a reshape that splits the minor dimension of a
 uint8 device array instead compiles for minutes and tens of GiB of host
-memory at 256 MiB on v5e (488 s, 28 GiB).  The host builds both expanded
-matrices once per (mat, G) (lru_cache), the kernel is shape-generic.
+memory at 256 MiB on v5e (488 s, 28 GiB).  Given n 1-D rows instead
+(RS decode's survivors, committed unstacked), the kernel takes each row
+G times, one [T] block of segment g per grid step, and stacks the kG
+blocks as the rows of its [kG, T] input in VMEM: the grouped input is
+never written to HBM.  The host builds both expanded matrices once per
+(mat, G) (lru_cache), the kernel is shape-generic.
 """
 from __future__ import annotations
 
@@ -81,7 +85,10 @@ def vmem_estimate(rows: int, n: int, G: int, tile: int) -> int:
     double-buffered by the pipeline.  Per column: the input and output
     byte blocks (double-buffered, rows padded to the 32-row uint8 tile),
     the unpacked bitplanes (int8), the int32 accumulator, its bf16
-    parity view and the packed f32.  Checked against the compiler's own
+    parity view and the packed f32.  The rows input's kG [T] blocks
+    take what the block input takes, and their [kG, T] stack in VMEM
+    adds kG bytes a column, within the model's ~2x slack (RS decode's
+    shape is compiled too).  Checked against the compiler's own
     figure in tests/test_chip_compile.py (which compiles every main-path
     geometry at the layout picked here) and by bisecting the limit on a
     described v5e: RS(8,4)@8192 needs 9.5 MiB (model 18.8),
@@ -133,8 +140,15 @@ def _unpack_bits(x, kG: int):
     ).reshape(8 * kG, x.shape[1])
 
 
-def _apply_kernel(B_ref, P_ref, x_ref, o_ref, *, kG: int):
-    bits = _unpack_bits(x_ref[:], kG)
+def _apply_kernel(B_ref, P_ref, *refs, kG: int):
+    """One [kG, T] input block, or kG 1-D [T] segments stacked here in
+    VMEM as its rows (the rows input of _apply_grouped)."""
+    *x_refs, o_ref = refs
+    if len(x_refs) == 1:
+        x = x_refs[0][:]
+    else:
+        x = jnp.stack([r[:] for r in x_refs])
+    bits = _unpack_bits(x, kG)
     acc = jax.lax.dot_general(
         B_ref[:],
         bits,
@@ -157,31 +171,51 @@ def _apply_grouped(
 ):
     """x: [n, Lp] uint8, Lp a multiple of G*tile -> [rows, Lp] uint8.
     Stacks the G column segments of x as the kernel's [n*G, Lp/G] input
-    (row g*n+j = segment g of row j) and unstacks its output."""
+    (row g*n+j = segment g of row j) and unstacks its output.
+
+    x may instead be a tuple of n 1-D uint8 rows of length L -> [rows,
+    L]: the rows are padded to a multiple of G*tile here, on the device,
+    and the kernel reads segment g of row j straight from the row, as
+    row g*n+j of its block, with no [n*G, Lp/G] copy in HBM."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     kG, rG = n * G, rows * G
-    Lp = x.shape[1]
-    if Lp % (G * tile):
-        raise ValueError(f"length {Lp} not a multiple of G*tile {G * tile}")
-    Lg = Lp // G
-    xg = jnp.concatenate([x[:, g * Lg:(g + 1) * Lg] for g in range(G)])
+    if isinstance(x, tuple):  # noqa: CL3 — a pytree's structure is static
+        L = x[0].shape[0]
+        Lp = L + (-L) % (G * tile)
+        if Lp != L:
+            x = tuple(jnp.pad(r, (0, Lp - L)) for r in x)
+        Lg = Lp // G
+        steps = Lg // tile
+        ins = [r for g in range(G) for r in x]
+        x_specs = [pl.BlockSpec((tile,), lambda i, g=g: (g * steps + i,))
+                   for g in range(G) for _ in x]
+    else:
+        L = Lp = x.shape[1]
+        if Lp % (G * tile):
+            raise ValueError(
+                f"length {Lp} not a multiple of G*tile {G * tile}")
+        Lg = Lp // G
+        ins = [jnp.concatenate([x[:, g * Lg:(g + 1) * Lg]
+                                for g in range(G)])]
+        x_specs = [pl.BlockSpec((kG, tile), lambda i: (0, i))]
     out = pl.pallas_call(
         partial(_apply_kernel, kG=kG),
         grid=(Lg // tile,),
         in_specs=[
             pl.BlockSpec(B.shape, lambda i: (0, 0)),
             pl.BlockSpec(P.shape, lambda i: (0, 0)),
-            pl.BlockSpec((kG, tile), lambda i: (0, i)),
+            *x_specs,
         ],
         out_specs=pl.BlockSpec((rG, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((rG, Lg), jnp.uint8),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(B, P, xg)
-    return jnp.concatenate(
+    )(B, P, *ins)
+    out = jnp.concatenate(
         [out[g * rows:(g + 1) * rows] for g in range(G)], axis=1)
+    return out[:, :L] if Lp != L else out
 
 
 def apply_matrix_pallas(
@@ -193,7 +227,9 @@ def apply_matrix_pallas(
     """GF(2^8) matrix apply via the fused Pallas kernel.
 
     Same contract (and bit-exact output) as
-    ceph_tpu.ops.bitplane.apply_matrix_jax: [rows, n] x [n, L] -> [rows, L].
+    ceph_tpu.ops.bitplane.apply_matrix_jax: [rows, n] x [n, L] -> [rows, L],
+    where `chunks` is one [n, L] array or a sequence of n 1-D uint8 rows
+    of length L (host or device; assembled and padded on the device).
     """
     mat = np.ascontiguousarray(mat, dtype=np.uint8)
     rows, n = mat.shape
@@ -202,6 +238,14 @@ def apply_matrix_pallas(
     Bk, Pk = _kron_matrices(mat.tobytes(), mat.shape, G)
     B = jnp.asarray(Bk)
     P = jnp.asarray(Pk, jnp.bfloat16)
+    if isinstance(chunks, (list, tuple)):
+        L = chunks[0].shape[0]
+        if len(chunks) != n or any(r.shape != (L,) or r.dtype != np.uint8
+                                   for r in chunks):
+            raise ValueError(f"want {n} 1-D uint8 rows of length {L}, got "
+                             f"{[(r.shape, r.dtype) for r in chunks]}")
+        return _apply_grouped(B, P, tuple(chunks), rows, n, G, tile,
+                              interpret)
     L = chunks.shape[1]
     pad = (-L) % (G * tile)
     if isinstance(chunks, np.ndarray):

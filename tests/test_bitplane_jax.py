@@ -126,6 +126,32 @@ def test_rs_decode_wanted_subset_of_lost():
             dev._jax_codec.rebuild_matrix_misses) == (1, 1)
 
 
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+@pytest.mark.parametrize("lost", [(1, 5, 9, 11), (0,), (8, 9, 10, 11)],
+                         ids=lambda t: "-".join(map(str, t)))
+def test_reconstruct_rows_from_two_arrays(monkeypatch, kernel, lost):
+    """Survivor rows taken as they are from two arrays, the data and the
+    parity, and passed unstacked: reconstruct rebuilds the lost shards
+    and decode the data, bit-exact with the numpy referee (both kernels;
+    the Pallas one in interpret mode)."""
+    monkeypatch.setenv("CEPH_TPU_EC_KERNEL", kernel)
+    dev, ref = _rs84_pair()
+    rng = np.random.default_rng(46 + len(lost))
+    L = 3000  # not a multiple of any tile: the pad is the device's
+    data = rng.integers(0, 256, (8, L), dtype=np.uint8)
+    parity = ref.encode_chunks(data)
+    have = {i: (data[i] if i < 8 else parity[i - 8])
+            for i in range(12) if i not in lost}
+    use = sorted(have)[:8]
+    rows = [have[i] for i in use]
+    want = ref.decode(set(lost), have, L)
+    got = np.asarray(dev._jax_codec.reconstruct(use, rows, list(lost)))
+    for w, i in zip(got, lost):
+        assert w.tobytes() == want[i].tobytes()
+    np.testing.assert_array_equal(
+        np.asarray(dev._jax_codec.decode(use, rows)), data)
+
+
 def test_decode_matrix_cache_hit():
     codec = BitplaneCodec(vandermonde_coding_matrix(4, 2))
     a = codec.decode_matrix((1, 2, 3, 4))

@@ -148,6 +148,49 @@ def test_gf_kernel_compiles(one_chip, matrix):
     _assert_named_for("gf_kernel_roofline", compiled.as_text())
 
 
+def test_decode_rows_compile(one_chip):
+    """RS(8,4) decode at the decode cell's shape: the k survivors as
+    eight 1-D rows of 8 MiB, read by the kernel straight from the rows,
+    under a [4, 8] rebuild matrix, in one launch.  The rows take the
+    bytes the [8, L] block takes (a 1-D uint8 layout padded on the chip
+    would show here)."""
+    import jax.numpy as jnp
+
+    from ceph_tpu.ops.pallas_gf import (
+        _apply_grouped,
+        _kron_matrices,
+        _pick_group,
+        _pick_layout,
+    )
+
+    codec = _codec({"plugin": "jax", "k": "8", "m": "4",
+                    "technique": "cauchy_good"})
+    use = (0, 2, 4, 5, 6, 8, 10, 11)
+    mat = codec._jax_codec._rebuild_entry(use, [1, 3, 7, 9])[0]
+    rows, n = mat.shape
+    assert (rows, n) == (4, 8)
+    L = 8 << 20
+    G = _pick_group(rows, n)
+    tile = _pick_layout(rows, n, G)
+    Bk, Pk = _kron_matrices(mat.tobytes(), mat.shape, G)
+
+    def compile_for(x):
+        return _apply_grouped.lower(
+            _spec(Bk.shape, jnp.int8, one_chip),
+            _spec(Pk.shape, jnp.bfloat16, one_chip), x,
+            rows=rows, n=n, G=G, tile=tile, interpret=False,
+        ).compile()
+
+    compiled = compile_for(
+        tuple(_spec((L,), jnp.uint8, one_chip) for _ in range(n)))
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    _assert_named_for("gf_kernel_roofline", compiled.as_text())
+    block = compile_for(_spec((n, L), jnp.uint8, one_chip))
+    got = compiled.memory_analysis().argument_size_in_bytes
+    want = block.memory_analysis().argument_size_in_bytes
+    assert abs(got - want) <= 0.05 * want, (got, want)
+
+
 def test_clay_relayouts_compile(one_chip):
     """The Clay repair's device relayouts around the apply, at the Clay
     cell's size: 11 helpers x 64 stripes x 16 planes of 8 KiB in, the

@@ -77,6 +77,35 @@ def test_non_tile_multiple_length_padded():
     np.testing.assert_array_equal(out, apply_ref(coding, data))
 
 
+@pytest.mark.parametrize("L,read_only", [
+    (8192, False), (3000, False), (8192, True),
+], ids=["tile_multiple", "padded_on_device", "read_only_rows"])
+def test_rows_bit_exact_with_one_array(L, read_only):
+    """n 1-D rows, assembled (and padded) on the device inside the
+    apply, give the bytes of the same rows passed as one [n, L] array;
+    read-only host rows (np.frombuffer) are taken as they are."""
+    k, m = 8, 4
+    coding = np.ascontiguousarray(cauchy_good_coding_matrix(k, m), np.uint8)
+    data = _rand(k, L, seed=L + read_only)
+    rows = ([np.frombuffer(data[j].tobytes(), np.uint8) for j in range(k)]
+            if read_only else list(data))
+    got = np.asarray(apply_matrix_pallas(coding, rows, tile=1024,
+                                         interpret=True))
+    want = np.asarray(apply_matrix_pallas(coding, data, tile=1024,
+                                          interpret=True))
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(got, apply_ref(coding, data))
+
+
+def test_rows_of_unequal_length_refused():
+    """Rows the kernel would read past the end of are refused."""
+    coding = np.ascontiguousarray(cauchy_good_coding_matrix(4, 2), np.uint8)
+    rows = list(_rand(4, 2048))
+    rows[2] = rows[2][:1024]
+    with pytest.raises(ValueError, match="1-D uint8 rows"):
+        apply_matrix_pallas(coding, rows, tile=1024, interpret=True)
+
+
 def test_fat_matrix_bit_exact():
     """Fat repair matrices (CLAY(8,4,d=11) is [64, 176]) must stay
     bit-exact vs the reference at a tile the layout picker may shrink

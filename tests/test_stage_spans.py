@@ -89,7 +89,9 @@ def test_encode_stages_and_bytes(tmp_path, shards):
 
 def test_decode_stages_and_bytes(tmp_path, shards):
     """Four erasures, two of them parity: one apply over the survivors
-    rebuilds exactly the four lost shards, through gf.*."""
+    rebuilds exactly the four lost shards, through gf.*.  The survivors
+    are not stacked on the host: their rows are committed under one
+    gf.h2d inside the decode."""
     data, parity = shards
     lost = {1, 5, 9, 11}
     have = {i: (data[i] if i < 8 else parity[i - 8])
@@ -102,15 +104,17 @@ def test_decode_stages_and_bytes(tmp_path, shards):
     for i in want:
         assert np.asarray(out[i]).tobytes() == want[i].tobytes()
     names = {s[0] for s in spans}
-    assert names == {"ec.decode", "ec.stack", "ec.decode_matrix", "gf.h2d",
+    assert names == {"ec.decode", "ec.decode_matrix", "gf.h2d",
                      "gf.dispatch", "gf.wait", "gf.d2h"}
     assert names <= set(DEVICE_STAGES)
     _assert_nested(spans, "gf.", {"ec.decode"})
-    _assert_nested(spans, "ec.stack", {"ec.decode"})
+    _assert_nested(spans, "gf.h2d", {"ec.decode"})
     _assert_nested(spans, "ec.decode_matrix", {"ec.decode"})
-    # one launch and one fetch of the lost rows, whatever is lost
+    # one commit of the survivors, one launch and one fetch of the lost
+    # rows, whatever is lost
     count = {n: sum(s[0] == n for s in spans) for n in names}
-    assert count["gf.dispatch"] == count["gf.wait"] == count["gf.d2h"] == 1
+    assert count["gf.h2d"] == count["gf.dispatch"] == count["gf.wait"] \
+        == count["gf.d2h"] == 1
     # a matrix build is a cache miss, one span each
     assert count["ec.decode_matrix"] == \
         codec._jax_codec.rebuild_matrix_misses == 1
