@@ -13,7 +13,7 @@ is partly taken, the block needs a second heap, which glibc unmaps as
 soon as it is empty again, so without a top pad as large as a heap the
 staging block is new memory every call as well.
 
-`retain_freed_large_blocks`, called where the RS and Clay codecs are
+`retain_freed_large_blocks`, called where the RS, Clay and SHEC codecs are
 built, raises the mmap threshold above those buffers, the trim
 threshold with it, and the top pad to a whole heap, so a freed block
 stays mapped and the next call's allocation of the same size reuses its

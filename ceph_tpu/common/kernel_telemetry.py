@@ -228,6 +228,29 @@ class KernelTelemetry:
                             "Clay repair-matrix cache",
                             "ec/plugins/clay.py")
 
+    def record_shec_matrix(self, hit: bool) -> None:
+        """The SHEC twin of record_rebuild_matrix: one lookup of the
+        plugin's decode-matrix cache (ec/plugins/shec.py
+        `ShecCodec._decode_entry`), as `shec_matrix_hits`/`_misses`."""
+        self._record_lookup("shec_matrix", hit,
+                            "SHEC decode-matrix cache",
+                            "ec/plugins/shec.py")
+
+    def record_shec_chunks_read(self, chunks: int) -> None:
+        """The chunks one SHEC decode was handed (ec/plugins/shec.py
+        `ShecCodec.decode_chunks`), added to `shec_chunks_read`."""
+        if not self.enabled:
+            return
+        if "shec_chunks_read" not in self._declared:
+            with self._lock:
+                if "shec_chunks_read" not in self._declared:
+                    self.perf._add(
+                        "shec_chunks_read", "u64",
+                        "chunks handed to SHEC decodes (ec/plugins/shec.py; "
+                        "docs/tracing.md)")
+                    self._declared.add("shec_chunks_read")
+        self.perf.inc("shec_chunks_read", int(chunks))
+
     def record_d2h(self, minor_faults: int) -> None:
         """One codec device-to-host fetch (ec/plugins/rs.py `_to_host`):
         `d2h_fetches` counts it, `d2h_minor_faults` adds the minor page

@@ -115,6 +115,8 @@ DEVICE_STAGES = (
     "clay.repair_matrix",  # repair-matrix build on a cache miss
     "clay.gather",        # helper buffers -> plane-major apply input
     "clay.scatter",       # apply output -> stripe-major chunk bytes
+    "shec.decode",        # ShecCodec.decode_chunks, the whole call
+    "shec.decode_matrix",  # plan and decode-matrix build on a cache miss
     "osdmap.map_pool",    # OSDMap.map_pool, the whole call
     "osdmap.post_pass",   # upmap overrides, up filter, primaries
     "crush.launch",       # xs/weight commit and each rule-fn dispatch

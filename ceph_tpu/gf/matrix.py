@@ -28,10 +28,8 @@ from .tables import gf_div, gf_inv, gf_mul
 def vandermonde_coding_matrix(k: int, m: int) -> np.ndarray:
     """m x k coding matrix, technique reed_sol_van.
 
-    Mirrors reed_sol.c :: reed_sol_vandermonde_coding_matrix — builds the
-    (k+m) x k "big" Vandermonde distribution matrix, converts the top k x k
-    block to identity with elementary *column* operations, scales columns so
-    the first coding row is all ones, and returns the bottom m rows.
+    Mirrors reed_sol.c :: reed_sol_vandermonde_coding_matrix: the last m
+    rows of the (k+m) x k distribution matrix below.
     """
     rows, cols = k + m, k
     if rows >= 256:
@@ -40,26 +38,42 @@ def vandermonde_coding_matrix(k: int, m: int) -> np.ndarray:
     return dist[cols:, :].copy()
 
 
-def big_vandermonde_distribution_matrix(rows: int, cols: int) -> np.ndarray:
-    """reed_sol.c :: reed_sol_big_vandermonde_distribution_matrix (w=8)."""
-    if rows < cols:
-        raise ValueError("rows < cols")
-    dist = np.zeros((rows, cols), dtype=np.int64)
-    for i in range(rows):
-        dist[i, 0] = 1
-        for j in range(1, cols):
-            dist[i, j] = gf_mul(int(dist[i, j - 1]), i)
+def extended_vandermonde_matrix(rows: int, cols: int) -> np.ndarray:
+    """reed_sol.c :: reed_sol_extended_vandermonde_matrix (w=8): row 0 is
+    [1, 0, ..., 0], the last row [0, ..., 0, 1], and row i between them
+    the powers i^0 .. i^(cols-1)."""
+    vdm = np.zeros((rows, cols), dtype=np.int64)
+    vdm[0, 0] = 1
+    if rows == 1:
+        return vdm
+    vdm[rows - 1, cols - 1] = 1
+    for i in range(1, rows - 1):
+        e = 1
+        for j in range(cols):
+            vdm[i, j] = e
+            e = gf_mul(e, i)
+    return vdm
 
-    # Gauss-Jordan by columns: make top cols x cols block the identity.
+
+def big_vandermonde_distribution_matrix(rows: int, cols: int) -> np.ndarray:
+    """reed_sol.c :: reed_sol_big_vandermonde_distribution_matrix (w=8).
+
+    Turns the extended Vandermonde matrix systematic: for each column i,
+    a row j >= i with a nonzero element in column i is swapped into row
+    i, column i is scaled so that element (i, i) is 1, and column
+    operations clear the rest of row i.  Then each column is scaled
+    below the identity so that row `cols` is all ones, and each later
+    row is scaled so that its first element is 1.
+    """
+    if rows <= cols:
+        raise ValueError("rows must exceed cols")
+    dist = extended_vandermonde_matrix(rows, cols)
     for i in range(1, cols):
-        # find a column j >= i with a nonzero pivot in row i
-        j = i
-        while j < cols and dist[i, j] == 0:
-            j += 1
-        if j == cols:
+        j = next((r for r in range(i, rows) if dist[r, i] != 0), None)
+        if j is None:
             raise ValueError("singular Vandermonde block (unexpected for w=8)")
         if j != i:
-            dist[:, [i, j]] = dist[:, [j, i]]
+            dist[[i, j]] = dist[[j, i]]
         if dist[i, i] != 1:
             inv = gf_div(1, int(dist[i, i]))
             for r in range(rows):
@@ -69,19 +83,18 @@ def big_vandermonde_distribution_matrix(rows: int, cols: int) -> np.ndarray:
             if j2 != i and tmp != 0:
                 for r in range(rows):
                     dist[r, j2] ^= gf_mul(tmp, int(dist[r, i]))
-
-    # Scale so the first coding row (row `cols`) is all ones; jerasure applies
-    # the compensating scaling only to rows below it (the identity rows' own
-    # compensation would be row scalings that cancel — it skips the no-op).
     for j in range(cols):
         tmp = int(dist[cols, j])
-        if tmp == 0:
-            raise ValueError("zero in first coding row (unexpected)")
         if tmp != 1:
             inv = gf_div(1, tmp)
-            dist[cols, j] = 1
-            for r in range(cols + 1, rows):
+            for r in range(cols, rows):
                 dist[r, j] = gf_mul(inv, int(dist[r, j]))
+    for i in range(cols + 1, rows):
+        tmp = int(dist[i, 0])
+        if tmp != 1:
+            inv = gf_div(1, tmp)
+            for j in range(cols):
+                dist[i, j] = gf_mul(int(dist[i, j]), inv)
     return dist
 
 

@@ -20,14 +20,14 @@ sharded across chips by ceph_tpu.parallel.
 
 The seam every codec shares is here, in two steps: a host stripe
 reaches the device by one `jnp.asarray(x, dtype=jnp.uint8)` under the
-`gf.h2d` stage (RS decode commits its k survivors as k 1-D rows, under
-one such stage, with no host stack), and `apply_matrix_jax` applies the
-GF(2^8) matrix to it (the fused Pallas kernel on TPU, `_apply_bitmatrix`
-elsewhere), leaving the result on the device for the caller to fetch.
-The RS codecs, both batchers, the EC backend's ranged decode and the
-stream pipeline commit that way; the SHEC and Clay plugins hand
-`apply_matrix_jax` host arrays, so their transfer is made, and timed,
-inside `gf.dispatch`.
+`gf.h2d` stage, and `apply_matrix_jax` applies the GF(2^8) matrix to it
+(the fused Pallas kernel on TPU, `_apply_bitmatrix` elsewhere), leaving
+the result on the device for the caller to fetch.  `commit` is that
+first step for RS encode and decode and the SHEC plugin: given a
+sequence of 1-D rows (RS decode's k survivors, SHEC's read set) it
+commits them in one `jax.device_put`, with no host stack.  The
+batchers, the EC backend's ranged decode, the stream pipeline and the
+Clay plugin commit under the same stage themselves.
 
 Bit-exactness: all ops are exact integer ops; tests assert parity bytes are
 identical to the C++ oracle (native/gf_oracle.cc).
@@ -216,6 +216,22 @@ def apply_matrix_jax(mat: np.ndarray, chunks,
     return out
 
 
+def commit(chunks):
+    """A GF apply's input on the device, under one `gf.h2d` stage: one
+    [n, L] array committed whole, or a sequence of 1-D uint8 rows of
+    length L committed row by row (no host copy stacks them; the apply
+    reads them on the device).  Rows stay 1-D: a [1, L] uint8 array may
+    be tiled to 32 sublanes on the chip.  RS decode and the SHEC plugin
+    commit so."""
+    with stage("gf.h2d"):
+        if isinstance(chunks, (list, tuple)):
+            # one batched, asynchronous commit of the uint8 rows: on a
+            # TPU v5e the decode cell's gf.h2d read 1.94 ms a call so,
+            # 2.41 with one jnp.asarray a row
+            return jax.device_put(list(chunks))  # noqa: CL8
+        return jnp.asarray(chunks, dtype=jnp.uint8)
+
+
 class BitplaneCodec:
     """Encode/decode a systematic RS code on TPU via the bitplane matmul.
 
@@ -271,18 +287,10 @@ class BitplaneCodec:
         return ent
 
     def _commit(self, shards):
-        """The first k survivors on the device, under one `gf.h2d` stage:
-        one [n, L] array committed whole, or a sequence of 1-D rows of
-        length L committed row by row (no host copy stacks them; the
-        apply reads them on the device).  Rows stay 1-D: a [1, L] uint8
-        array may be tiled to 32 sublanes on the chip."""
-        with stage("gf.h2d"):
-            if isinstance(shards, (list, tuple)):
-                # one batched, asynchronous commit of the uint8 rows: on
-                # a TPU v5e the decode cell's gf.h2d read 1.94 ms a call
-                # so, 2.41 with one jnp.asarray a row
-                return jax.device_put(list(shards[: self.k]))  # noqa: CL8
-            return jnp.asarray(shards, dtype=jnp.uint8)[: self.k]
+        """The first k survivors on the device (`commit`)."""
+        if isinstance(shards, (list, tuple)):
+            return commit(shards[: self.k])
+        return commit(shards)[: self.k]
 
     def decode(self, available_rows, shards) -> jnp.ndarray:
         """Rebuild the k data shards from >= k surviving shards.

@@ -78,19 +78,22 @@ void gfo_mul_table(uint8_t* out) { std::memcpy(out, T.mul, 256 * 256); }
 // the bottom m rows (reed_sol_vandermonde_coding_matrix).
 int gfo_vandermonde(int k, int m, uint8_t* out) {
   const int rows = k + m, cols = k;
-  if (rows >= 256 || rows < cols) return -1;
+  if (rows >= 256 || rows <= cols) return -1;
   std::vector<int> d((size_t)rows * cols, 0);
   auto at = [&](int r, int c) -> int& { return d[(size_t)r * cols + c]; };
-  for (int i = 0; i < rows; ++i) {
+  // reed_sol_extended_vandermonde_matrix: [1,0..0], powers of i, [0..0,1]
+  at(0, 0) = 1;
+  at(rows - 1, cols - 1) = 1;
+  for (int i = 1; i < rows - 1; ++i) {
     at(i, 0) = 1;
     for (int j = 1; j < cols; ++j) at(i, j) = gmul(at(i, j - 1), i);
   }
   for (int i = 1; i < cols; ++i) {
     int j = i;
-    while (j < cols && at(i, j) == 0) ++j;
-    if (j == cols) return -2;
+    while (j < rows && at(j, i) == 0) ++j;
+    if (j == rows) return -2;
     if (j != i)
-      for (int r = 0; r < rows; ++r) std::swap(at(r, i), at(r, j));
+      for (int c = 0; c < cols; ++c) std::swap(at(i, c), at(j, c));
     if (at(i, i) != 1) {
       const int inv = gdiv(1, at(i, i));
       for (int r = 0; r < rows; ++r) at(r, i) = gmul(inv, at(r, i));
@@ -101,13 +104,22 @@ int gfo_vandermonde(int k, int m, uint8_t* out) {
         for (int r = 0; r < rows; ++r) at(r, j2) ^= gmul(tmp, at(r, i));
     }
   }
+  // row k all ones: scale each column below the identity
   for (int j = 0; j < cols; ++j) {
     const int tmp = at(cols, j);
     if (tmp == 0) return -3;
     if (tmp != 1) {
       const int inv = gdiv(1, tmp);
-      at(cols, j) = 1;
-      for (int r = cols + 1; r < rows; ++r) at(r, j) = gmul(inv, at(r, j));
+      for (int r = cols; r < rows; ++r) at(r, j) = gmul(inv, at(r, j));
+    }
+  }
+  // each later row's first element 1: scale the row
+  for (int i = cols + 1; i < rows; ++i) {
+    const int tmp = at(i, 0);
+    if (tmp == 0) return -3;
+    if (tmp != 1) {
+      const int inv = gdiv(1, tmp);
+      for (int j = 0; j < cols; ++j) at(i, j) = gmul(at(i, j), inv);
     }
   }
   for (int i = 0; i < m; ++i)

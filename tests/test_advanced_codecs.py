@@ -5,7 +5,9 @@ src/test/erasure-code/TestErasureCodeShec*.cc — exhaustive erasure-pattern
 sweeps; TestErasureCodeLrc.cc — layer semantics; TestErasureCodeClay.cc —
 sub-chunk repair; SURVEY.md §4 ring 1).
 """
+import importlib.util
 import itertools
+import os
 
 import numpy as np
 import pytest
@@ -13,6 +15,16 @@ import pytest
 from ceph_tpu.ec import ErasureCodePluginRegistry, InsufficientChunks, InvalidProfile
 
 REG = ErasureCodePluginRegistry.instance()
+SHEC_REF = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))), "benchmark", "reference", "shec_ref.py")
+
+
+def _shec_ref():
+    """benchmark/reference/shec_ref.py, loaded by its path."""
+    spec = importlib.util.spec_from_file_location("shec_ref", SHEC_REF)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def _shards(codec, seed=0, sub_mult=1):
@@ -43,12 +55,18 @@ class TestShec:
 
     def test_single_erasure_local_recovery(self):
         shards = _shards(self.codec)
-        n, k = 9, 6
+        n = 9
+        ref = _shec_ref()
+        coding = ref.coding_matrix({"k": 6, "m": 3, "c": 2})
         for e in range(n):
             avail = set(range(n)) - {e}
             md = self.codec.minimum_to_decode({e}, avail)
-            # locality: fewer than k chunks read (that's SHEC's whole point)
-            assert len(md) < k, (e, sorted(md))
+            # locality: the least read set, 3 chunks for a data chunk or
+            # a local parity and 6 for the global parity (shingles
+            # d0-d5, d0-d2, d3-d5)
+            assert len(md) == len(ref.minimum_read({e}, 6, 3, coding)), (
+                e, sorted(md))
+            assert len(md) == (6 if e == 6 else 3), (e, sorted(md))
             have = {i: shards[i] for i in md}
             out = self.codec.decode_chunks({e}, have)
             np.testing.assert_array_equal(out[e], shards[e])

@@ -191,6 +191,47 @@ def test_decode_rows_compile(one_chip):
     assert abs(got - want) <= 0.05 * want, (got, want)
 
 
+@pytest.mark.parametrize("lost,layout", [(2, (1, 3, 32, 8192)),
+                                         (6, (1, 6, 16, 8192))],
+                         ids=["data", "global_parity"])
+def test_shec_decode_rows_compile(one_chip, lost, layout):
+    """SHEC(6,3,2) single-erasure decode at the SHEC cell's shape: the
+    least read set (3 rows, or 6 for the global parity) as 1-D rows of
+    12 x 4 MiB / 6 padded to 171 stripe units of 4 KiB, 8,404,992 B (not
+    a power of two), under a [1, n] decode matrix, in one launch.  The
+    rows are padded to the layout on the device: the arguments are the
+    rows' own bytes and the two expanded matrices, nothing more."""
+    import jax.numpy as jnp
+
+    from ceph_tpu.ops.pallas_gf import (
+        _apply_grouped,
+        _kron_matrices,
+        _pick_group,
+        _pick_layout,
+    )
+
+    codec = _codec({"plugin": "shec", "k": "6", "m": "3", "c": "2"})
+    plan = codec.minimum_to_decode({lost}, set(range(9)) - {lost})
+    mat = codec._decode_matrix(frozenset({lost}), tuple(sorted(plan)))
+    rows, n = mat.shape
+    G = _pick_group(rows, n)
+    tile = _pick_layout(rows, n, G)
+    assert (rows, n, G, tile) == layout
+    # a 4 MiB object is 171 stripes of 6 x 4 KiB: a 700,416 B chunk
+    L = 12 * 171 * 4096
+    Bk, Pk = _kron_matrices(mat.tobytes(), mat.shape, G)
+    compiled = _apply_grouped.lower(
+        _spec(Bk.shape, jnp.int8, one_chip),
+        _spec(Pk.shape, jnp.bfloat16, one_chip),
+        tuple(_spec((L,), jnp.uint8, one_chip) for _ in range(n)),
+        rows=rows, n=n, G=G, tile=tile, interpret=False,
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    _assert_named_for("gf_kernel_roofline", compiled.as_text())
+    assert compiled.memory_analysis().argument_size_in_bytes == \
+        Bk.nbytes + 2 * Pk.size + n * L
+
+
 def test_clay_relayouts_compile(one_chip):
     """The Clay repair's device relayouts around the apply, at the Clay
     cell's size: 11 helpers x 64 stripes x 16 planes of 8 KiB in, the

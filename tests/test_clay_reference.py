@@ -4,10 +4,10 @@ loaded by its path): parity, single-shard repair from the helpers'
 repair-plane sub-chunks alone over several stripes, and whole-chunk
 repair, bit for bit for every lost shard.
 
-The inner code is cauchy_good at m = 4 and cauchy_orig at m = 2: the
-program's reed_sol_van is not jerasure's for m >= 2, and the reference
-builds cauchy_good only for m > 2 (jerasure takes m = 2 from its cbest
-tables).
+The inner code is cauchy_good at m = 4 and cauchy_orig at m = 2 (the
+reference builds cauchy_good only for m > 2: jerasure takes m = 2 from
+its cbest tables); the default inner code, reed_sol_van, is checked
+against the reference on its own.
 """
 import hashlib
 import importlib.util
@@ -127,17 +127,20 @@ def test_unknown_scalar_mds_is_refused():
 
 @pytest.mark.parametrize("profile,digest", [
     ({"plugin": "clay", "k": "4", "m": "2"},
-     "a7db5641ed923b7f74b6e6e01ac28055806e04897ffb609df155334d9b08de8e"),
+     "7af18847629d217c426ca3b565476f8fb43dcf3ca732a1a6e0e83cc4f2763042"),
     ({"plugin": "clay", "k": "8", "m": "4", "d": "11"},
-     "93a93ce3b7ef67819429bf7330cd5fd478a4ff7c3d57326e3f5954c66555f8b4"),
+     "8d3e6834673baac703c45c64136f6c8ca0b8fb2fc6d4b837047709a02f752066"),
 ], ids=["clay42", "clay84_d11"])
-def test_default_profile_parity_is_unchanged(profile, digest):
-    """The default inner code (jerasure reed_sol_van) gives the parity
-    the plugin wrote before `scalar_mds` and `technique` were read."""
+def test_default_profile_parity_is_unchanged(clay_ref, profile, digest):
+    """The default inner code is jerasure's reed_sol_van: the parity is
+    the reference's with that inner code, and its digest is pinned."""
     codec = REG.factory(profile)
     data = np.random.default_rng(2026).integers(
         0, 256, (codec.k, 64 * codec.sub_chunk_count), dtype=np.uint8)
     parity = np.asarray(codec.encode_chunks(data))
+    Z = codec.sub_chunk_count
+    want = clay_ref.encode(profile, data.reshape(codec.k, Z, 64))
+    np.testing.assert_array_equal(parity, want.reshape(codec.m, Z * 64))
     assert hashlib.sha256(parity.tobytes()).hexdigest() == digest
 
 

@@ -5,6 +5,9 @@ Models the reference's isa<->jerasure parity cross-check
 reed_sol_van produce identical parity", SURVEY.md §4 ring 1): two independent
 implementations of the same constructions must agree bit-for-bit.
 """
+import importlib.util
+import os
+
 import numpy as np
 import pytest
 
@@ -39,11 +42,24 @@ def test_scalar_ops_spot():
         assert oracle.n_ones(n) == cauchy_n_ones(n)
 
 
+def _gf_ref():
+    """benchmark/reference/gf_ref.py (jerasure's constructions, written
+    apart from the program), loaded by its path."""
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__))), "benchmark", "reference", "gf_ref.py")
+    spec = importlib.util.spec_from_file_location("gf_ref", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 @pytest.mark.parametrize("k,m", KM_GRID)
 def test_vandermonde_identical(k, m):
-    np.testing.assert_array_equal(
-        oracle.vandermonde(k, m), vandermonde_coding_matrix(k, m).astype(np.uint8)
-    )
+    """The oracle, the program and the plain reference build jerasure's
+    reed_sol_van alike."""
+    want = vandermonde_coding_matrix(k, m).astype(np.uint8)
+    np.testing.assert_array_equal(oracle.vandermonde(k, m), want)
+    np.testing.assert_array_equal(_gf_ref().reed_sol_van(k, m), want)
 
 
 @pytest.mark.parametrize("k,m", KM_GRID)

@@ -164,6 +164,53 @@ def test_clay_repair_stages_and_bytes(tmp_path):
     assert codec.repair_matrix_hits == 1
 
 
+def test_shec_decode_stages_and_bytes(tmp_path, monkeypatch):
+    """A SHEC(6,3,2) single-erasure decode from its least read set: one
+    shec.decode around the plan and matrix build, one commit of the
+    three rows, one launch, one wait and one fetch; the matrix cache and
+    chunk counters move, and a second decode finds its matrix cached."""
+    from ceph_tpu.common.kernel_telemetry import TELEMETRY
+    from ceph_tpu.ec.registry import ErasureCodePluginRegistry
+
+    monkeypatch.setattr(TELEMETRY, "enabled", True)
+
+    def counter(name):
+        return TELEMETRY.perf.dump().get(name, 0)
+
+    codec = ErasureCodePluginRegistry.instance().factory(
+        {"plugin": "shec", "k": "6", "m": "3", "c": "2"})
+    data = np.random.default_rng(6).integers(0, 256, (6, 4096),
+                                             dtype=np.uint8)
+    shards = dict(enumerate(np.concatenate([data,
+                                            codec.encode_chunks(data)])))
+    lost = 4
+    plan = codec.minimum_to_decode({lost}, set(shards) - {lost})
+    have = {c: shards[c] for c in plan}
+    before = {n: counter(n) for n in ("shec_matrix_hits",
+                                      "shec_matrix_misses",
+                                      "shec_chunks_read")}
+    out, spans = _profiled(tmp_path,
+                           lambda: codec.decode({lost}, have, 4096))
+    assert out[lost].tobytes() == data[lost].tobytes()
+    names = {s[0] for s in spans}
+    assert names == {"shec.decode", "shec.decode_matrix", "gf.h2d",
+                     "gf.dispatch", "gf.wait", "gf.d2h"}
+    assert names <= set(DEVICE_STAGES)
+    for inner in ("shec.decode_matrix", "gf."):
+        _assert_nested(spans, inner, {"shec.decode"})
+    count = {n: sum(s[0] == n for s in spans) for n in names}
+    assert count["shec.decode"] == count["gf.h2d"] == \
+        count["gf.dispatch"] == count["gf.wait"] == count["gf.d2h"] == 1
+    assert counter("shec_matrix_misses") == before["shec_matrix_misses"] + 1
+    assert counter("shec_chunks_read") == \
+        before["shec_chunks_read"] + len(plan) == \
+        before["shec_chunks_read"] + 3
+    _, again = _profiled(tmp_path / "again",
+                         lambda: codec.decode({lost}, have, 4096))
+    assert "shec.decode_matrix" not in {s[0] for s in again}
+    assert counter("shec_matrix_hits") == before["shec_matrix_hits"] + 1
+
+
 def _osdmap():
     from ceph_tpu.crush import CrushWrapper, build_hierarchical_map
     from ceph_tpu.osd import OSDMap
